@@ -54,6 +54,8 @@ def main() -> None:
     ap.add_argument("--out", default="results/bench")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.suite in ("kernels", "all"):
         from benchmarks import bench_kernels
         bench_kernels.main(out_dir=args.out)

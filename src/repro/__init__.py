@@ -1,7 +1,1 @@
-"""Paper reproduction package.
-
-Importing the package installs the jax version-compat backfills (see
-:mod:`repro.compat`) before any module touches the moved APIs.
-"""
-
-from . import compat  # noqa: F401  (side effect: jax API backfills)
+"""Paper reproduction package."""

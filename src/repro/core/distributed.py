@@ -37,13 +37,15 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from .contract import CostStats, entity_onehot, _onehot, _expand
 from .ct import CtTable
 from .database import RelationalDB
 from .executors import EXECUTORS, SparseExecutor, _kr_segment_sum
+from .mobius import superset_mobius
 from .variables import Atom, CtVar, LatticePoint, Var, edge_var
 
 
@@ -57,6 +59,20 @@ def _pad_to(arr: np.ndarray, mult: int) -> Tuple[np.ndarray, np.ndarray]:
         arr = np.concatenate([arr, np.zeros((pad,) + arr.shape[1:], arr.dtype)])
         w[n:] = 0.0
     return arr, w
+
+
+def _unsharded(x: jax.Array) -> jax.Array:
+    """``x`` as a plain array on the first device of its mesh, with no mesh
+    in its type: the form in which every table leaves a sharded hop.
+
+    The engine mixes these tables with its single-device arrays (reshapes,
+    ``complete_ct``'s ``dynamic_update_slice``).  On a mesh with
+    ``Explicit`` axes, what ``jax.make_mesh`` builds by default, a
+    mesh-typed operand makes each such mix a sharding type error.  A
+    replicated ``x`` is handed over without a copy; a sharded one is
+    gathered."""
+    return jax.device_put(
+        x, SingleDeviceSharding(x.addressable_shards[0].device))
 
 
 def _segsum_shard_kernel(total: int):
@@ -137,7 +153,7 @@ def sharded_positive_ct(db: RelationalDB, point: LatticePoint,
     if keep is None:
         keep = [v for v in point.all_ct_vars(schema, include_rind=False)]
     keep = list(keep)
-    nsh = int(np.prod([mesh.shape[a] for a in (axis,)]))
+    nsh = int(mesh.shape[axis])
 
     adj: Dict[Var, List[Tuple[Atom, Var]]] = {}
     for a in point.atoms:
@@ -173,8 +189,9 @@ def sharded_positive_ct(db: RelationalDB, point: LatticePoint,
                       and mesh.shape["model"] > 1 else None)
             fn = _sharded_hop(mesh, axis, n_parent, len(hots), dtype,
                               value_axis=v_axis)
-            hop_out = fn(child_msg, jnp.asarray(gidx), jnp.asarray(sidx),
-                         jnp.asarray(w), *hots)
+            child_msg = jax.device_put(child_msg, NamedSharding(mesh, P()))
+            hop_out = _unsharded(fn(child_msg, jnp.asarray(gidx),
+                                    jnp.asarray(sidx), jnp.asarray(w), *hots))
             if stats is not None:
                 stats.joins += 1
                 stats.rows_scanned += int(gidx.shape[0])
@@ -262,6 +279,7 @@ class ShardedSparseExecutor(SparseExecutor):
         self.mesh = mesh
         self.axis = axis
         self.n_ranks = int(mesh.shape[axis])
+        self._row_sharding = NamedSharding(mesh, P(axis))
         # (kind, segment space, padded rows, widths...) -> jitted shard_map
         # closure; one trace per key, flat across a flood
         self._shard_fn_cache: Dict[Tuple, object] = {}
@@ -282,6 +300,12 @@ class ShardedSparseExecutor(SparseExecutor):
             yield self
         finally:
             self._force_local = prev
+
+    def shard_rows(self, arr) -> jax.Array:
+        """Place a row array (edge or entity rows, padded to a multiple of
+        the rank count) split over ``axis``: the layout every sharded
+        primitive takes its inputs in."""
+        return jax.device_put(arr, self._row_sharding)
 
     # -- shard_map closure cache --------------------------------------------
     def _shard_fn(self, key: Tuple, build):
@@ -378,12 +402,12 @@ class ShardedSparseExecutor(SparseExecutor):
         if rows is None:
             fn = self._shard_fn(("edge_ones", total, int(seg.shape[0])),
                                 self._build_edge_ones)
-            return fn(jnp.asarray(seg), jnp.asarray(w))
+            return _unsharded(fn(self.shard_rows(seg), self.shard_rows(w)))
 
         rows_p = jnp.pad(rows, ((0, seg.shape[0] - rows.shape[0]), (0, 0)))
         fn = self._shard_fn(("edge_dense", total, int(seg.shape[0]),
                              int(rows_p.shape[1])), self._build_edge_dense)
-        return fn(jnp.asarray(seg), rows_p)
+        return _unsharded(fn(self.shard_rows(seg), self.shard_rows(rows_p)))
 
     def _reduce_by_code(self, code, ds: int, n: int,
                         factors: Sequence[jnp.ndarray]) -> jnp.ndarray:
@@ -395,16 +419,18 @@ class ShardedSparseExecutor(SparseExecutor):
         if not factors:
             fn = self._shard_fn(("reduce_ones", ds, int(code_p.shape[0])),
                                 self._build_reduce_ones)
-            return fn(jnp.asarray(code_p), jnp.asarray(w))
+            return _unsharded(fn(self.shard_rows(code_p),
+                                 self.shard_rows(w)))
 
         n_pad = int(code_p.shape[0])
         # no weight mask here: the factor rows are zero-padded, so padding
         # contributes nothing to segment 0
-        mats = [jnp.pad(f, ((0, n_pad - n), (0, 0))) for f in factors]
+        mats = [self.shard_rows(jnp.pad(f, ((0, n_pad - n), (0, 0))))
+                for f in factors]
         widths = tuple(int(m.shape[1]) for m in mats)
         fn = self._shard_fn(("reduce_kr", ds, n_pad, widths),
                             self._build_reduce_kr)
-        return fn(jnp.asarray(code_p), *mats).reshape(-1)
+        return _unsharded(fn(self.shard_rows(code_p), *mats)).reshape(-1)
 
     # -- batching -----------------------------------------------------------
     def _positive_stacked(self, db, plans, stats):
@@ -496,12 +522,14 @@ def merge_stacked(stacked: jnp.ndarray, axis_name: str = "data"
 def superset_mobius_sharded(stack: jnp.ndarray, k: int, *, mesh: Mesh,
                             axis: str = "model") -> jnp.ndarray:
     """Möbius butterfly with the flattened attribute axis sharded over
-    ``axis``: the transform is elementwise across attributes, so no
-    communication is needed — only the layout constraint.
+    ``axis``: the transform is elementwise across attributes, so each rank
+    transforms its own columns under ``shard_map`` and no communication is
+    needed.
 
     Args:
         stack: the butterfly input; the leading ``k`` axes are the binary
-            indicator axes, the rest is the attribute value space.
+            indicator axes, the rest is the attribute value space, whose
+            flattened size must divide evenly over ``axis``.
         k: number of leading indicator axes to transform over.
         mesh / axis: device mesh and the axis to shard attributes over.
 
@@ -514,12 +542,7 @@ def superset_mobius_sharded(stack: jnp.ndarray, k: int, *, mesh: Mesh,
     """
     lead = stack.shape[:k]
     d = int(np.prod(stack.shape[k:])) if stack.ndim > k else 1
-    x = stack.reshape(lead + (d,))
     spec = P(*([None] * k + [axis]))
-    x = jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, spec))
-    for i in range(k):
-        x0 = jnp.take(x, 0, axis=i) - jnp.take(x, 1, axis=i)
-        x1 = jnp.take(x, 1, axis=i)
-        x = jnp.stack([x0, x1], axis=i)
-    return x.reshape(stack.shape)
+    fn = shard_map(lambda x: superset_mobius(x, k), mesh=mesh,
+                   in_specs=spec, out_specs=spec, check_vma=False)
+    return jax.jit(fn)(stack.reshape(lead + (d,))).reshape(stack.shape)

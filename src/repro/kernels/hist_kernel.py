@@ -31,11 +31,14 @@ def _hist_kernel(codes_ref, vals_ref, o_ref, *, block_p: int):
     base = p_idx * block_p
     seg = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], block_p), 1)
     onehot = (codes[:, None] - base == seg).astype(jnp.float32)  # (Nc, Pb)
-    o_ref[...] += jnp.dot(onehot.T, vals, preferred_element_type=jnp.float32)
+    # HIGHEST: full f32 passes; bf16 operands would round values
+    o_ref[...] += jnp.dot(onehot.T, vals,
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
 
 
 def segment_hist_pallas(codes: jnp.ndarray, values: jnp.ndarray,
-                        num_segments: int, *, block_n: int = 512,
+                        num_segments: int, *, block_n: int = 1024,
                         block_p: int = 256, block_d: int = 256,
                         interpret: bool = True) -> jnp.ndarray:
     """Weighted histogram of ``values`` [N, D] into ``num_segments`` rows.

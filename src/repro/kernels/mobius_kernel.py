@@ -35,7 +35,9 @@ def mobius_matrix(k: int, dtype=np.float32) -> np.ndarray:
 def _mobius_kernel(t_ref, x_ref, o_ref):
     t = t_ref[...]
     x = x_ref[...]
-    o_ref[...] = jnp.dot(t, x, preferred_element_type=jnp.float32)
+    # HIGHEST: full f32 passes; bf16 operands would round counts above 256
+    o_ref[...] = jnp.dot(t, x, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
 
 
 def mobius_pallas(stack: jnp.ndarray, *, block_d: int = 512,

@@ -4,23 +4,26 @@ probe that decides how they lower.
 Every wrapper takes ``interpret=None`` and resolves it through
 :func:`default_interpret`: one probe of ``jax.default_backend()`` —
 CPU → ``True`` (the Pallas interpreter; Mosaic/Triton lowering needs a
-real accelerator), TPU/GPU → ``False`` (native lowering).  The
-``REPRO_PALLAS_INTERPRET`` environment variable (``1``/``0``,
-``true``/``false``) overrides the probe in both directions — forcing
-interpret mode on an accelerator for debugging, or asserting native
-lowering in a deployment where falling back to the interpreter would be
-a silent 1000x regression.  Resolution happens *outside* the jitted
-inner functions, so flipping the env var between calls takes effect
-immediately (the bool is a static jit argument either way).
+real accelerator), TPU/GPU → ``False`` (native lowering).  If the
+backend cannot be started the probe raises: it never falls back to the
+interpreter, so a broken accelerator fails loudly instead of running
+kernels a thousand times slower.  Resolution happens *outside* the
+jitted inner functions, so flipping an override between calls takes
+effect immediately (the bool is a static jit argument either way).
 
 :func:`segsum_kernel_enabled` is the matching routing predicate for the
 sparse executors' scatter-add hop (:mod:`.segsum_kernel`): on by default
 only on accelerators (the interpreted kernel body is Python — orders of
-magnitude slower than XLA's native scatter on CPU), forceable on CPU CI
-with ``REPRO_SEGSUM_PALLAS=1`` for kernel-parity coverage, and always
-capped at ``SEGSUM_KERNEL_MAX_SEGMENTS`` because the one-hot sweep costs
+magnitude slower than XLA's native scatter on CPU), and always capped at
+``SEGSUM_KERNEL_MAX_SEGMENTS`` because the one-hot sweep costs
 O(edges x segments) — huge flattened ``(parent, code)`` spaces stay on
 ``jax.ops.segment_sum``.
+
+Two environment variables override the probe, for debugging only:
+``REPRO_PALLAS_INTERPRET`` (``1``/``0``) forces interpret mode or native
+lowering, and ``REPRO_SEGSUM_PALLAS`` (``1``/``0``) forces the segment-sum
+route on or off (the CPU tests use it for kernel-parity coverage).  A
+measured run must set neither; ``chip_smoke.py`` refuses both.
 """
 
 from __future__ import annotations
@@ -51,10 +54,8 @@ def _env_flag(name: str) -> Optional[bool]:
 
 @functools.lru_cache(maxsize=None)
 def _on_accelerator() -> bool:
-    try:
-        return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
-    except Exception:                      # no backend at all -> interpret
-        return False
+    # a backend that fails to start raises here: there is no fallback
+    return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
 
 
 def default_interpret() -> bool:

@@ -33,6 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# full f32 passes on the MXU: the default rounds operands to bf16, which
+# is exact for the one-hot side but not for counts above 256
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _rows_kernel(seg_ref, rows_ref, o_ref, *, block_p: int):
     p_idx = pl.program_id(0)
@@ -47,7 +51,7 @@ def _rows_kernel(seg_ref, rows_ref, o_ref, *, block_p: int):
     base = p_idx * block_p
     col = jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], block_p), 1)
     onehot = (seg[:, None] - base == col).astype(jnp.float32)   # (Nb, Pb)
-    o_ref[...] += jnp.dot(onehot.T, rows,
+    o_ref[...] += jnp.dot(onehot.T, rows, precision=_EXACT,
                           preferred_element_type=jnp.float32)
 
 
@@ -64,12 +68,12 @@ def _ones_kernel(seg_ref, w_ref, o_ref, *, block_p: int):
     base = p_idx * block_p
     col = jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], block_p), 1)
     onehot = (seg[:, None] - base == col).astype(jnp.float32)   # (Nb, Pb)
-    o_ref[...] += jnp.dot(w[None, :], onehot,
+    o_ref[...] += jnp.dot(w[None, :], onehot, precision=_EXACT,
                           preferred_element_type=jnp.float32)   # (1, Pb)
 
 
 def segment_sum_rows_pallas(seg: jnp.ndarray, rows: jnp.ndarray,
-                            num_segments: int, *, block_n: int = 512,
+                            num_segments: int, *, block_n: int = 1024,
                             block_p: int = 256, block_d: int = 256,
                             interpret: bool = True) -> jnp.ndarray:
     """``out[p, d] = sum_{e: seg[e]==p} rows[e, d]`` for ``rows`` [N, D].

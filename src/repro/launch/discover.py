@@ -35,6 +35,7 @@ from repro.core.distributed import sharded_positive_ct, _sharded_hop
 from repro.core.search import discover_model
 from repro.core.strategies import make_strategy
 from repro.hlo_analysis import analyze as analyze_hlo
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.roofline import roofline_terms
 
@@ -117,6 +118,7 @@ def main() -> int:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.dryrun:
         if os.environ.get("REPRO_DRYRUN") != "1":
             print("set REPRO_DRYRUN=1 (before python starts) for --dryrun",

@@ -157,7 +157,7 @@ def sharded_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if tp == 1 or hq % tp == 0 or sq % tp != 0 or q.shape[0] == 0:
         return block_attention(q, k, v, causal, chunk, kv_valid)
 
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     names = set(am.axis_names)
     fsdp = tuple(a for a in ("pod", "data") if a in names)

@@ -125,7 +125,7 @@ def chunked_linear_attention(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         S_final, S0s = jax.lax.scan(comb, state0, (S_loc, decay))
         outs = jax.vmap(chunk_out)(rr, kk, vv, lw, S0s)
     else:
-        from ..compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         cspec = P("model", bspec, None, None, None)       # [n, b, h, c, d]
         sspec = P("model", bspec, None, None, None)       # [n, b, h, dk, dv]

@@ -77,7 +77,7 @@ def _moe_apply_ep(p: MoeParams, x: jnp.ndarray, cfg: ModelConfig, am
     to token order, and one ``psum`` over ``model`` combines.  Dispatch
     moves ZERO bytes over links; combine costs one [b_l, S, D] all-reduce
     per layer — the same wire cost as a dense TP MLP."""
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     names = set(am.axis_names)

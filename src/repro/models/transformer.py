@@ -18,7 +18,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
 
 from .attention import (AttnParams, attn_init, block_attention,
                         combine_partials, decode_partial, qkv_project,
