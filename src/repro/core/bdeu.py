@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.special import gammaln
 
-from .ct import CtTable
+from ..obs.trace import NULL_TRACER, NullTracer
+from .ct import CtTable, to_host
 from .variables import CtVar
 
 
@@ -57,9 +58,10 @@ def family_nijk(tab: CtTable, child: CtVar) -> jnp.ndarray:
 
 
 def family_score(tab: CtTable, child: CtVar, ess: float = 1.0,
-                 score_fn=None) -> float:
+                 score_fn=None, tracer: NullTracer = NULL_TRACER) -> float:
     """Score a family from its complete ct-table.  ``tab`` must contain the
-    child axis and any number of parent axes."""
+    child axis and any number of parent axes.  The score's read back to
+    the host is a ``host.read`` span on ``tracer``."""
     nijk = family_nijk(tab, child)
     fn = score_fn or bdeu_score_2d
-    return float(fn(nijk, ess=ess))
+    return float(to_host(fn(nijk, ess=ess), tracer, "family_score"))

@@ -27,14 +27,13 @@ The sparse executor replaces this with O(nnz) scatter-adds; see
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.hist import LatencyHistogram
 from .ct import CtTable
 from .database import RelationalDB
 from .variables import CtVar, LatticePoint, Var, attr_var
@@ -48,11 +47,6 @@ class CostStats:
     .cache.CtCache` bumps it on insert and **decrements it on eviction or
     drop**, so ``peak_bytes`` (the Fig. 4 memory proxy) is a true
     high-water mark even under a byte budget.
-
-    Beyond the Fig. 3 *totals*, each timed phase also feeds a
-    log-bucketed :class:`~repro.obs.hist.LatencyHistogram` in
-    ``phase_hists`` — per-interval p50/p95/p99 for metadata/positive/
-    negative work, surfaced under ``"phases"`` in :meth:`as_dict`.
     """
     joins: int = 0                # number of edge-table join sweeps
     rows_scanned: int = 0         # edge rows touched by joins
@@ -63,17 +57,10 @@ class CostStats:
     time_metadata: float = 0.0    # Fig. 3 decomposition
     time_positive: float = 0.0
     time_negative: float = 0.0
-    phase_hists: Dict[str, LatencyHistogram] = field(default_factory=dict)
 
     def bump_cache(self, delta: int) -> None:
         self.cache_bytes += delta
         self.peak_bytes = max(self.peak_bytes, self.cache_bytes)
-
-    def observe_phase(self, which: str, dt: float) -> None:
-        h = self.phase_hists.get(which)
-        if h is None:
-            h = self.phase_hists[which] = LatencyHistogram()
-        h.observe(dt)
 
     class _Timer:
         def __init__(self, stats: "CostStats", which: str) -> None:
@@ -87,7 +74,6 @@ class CostStats:
             dt = time.perf_counter() - self.t0
             setattr(self.stats, f"time_{self.which}",
                     getattr(self.stats, f"time_{self.which}") + dt)
-            self.stats.observe_phase(self.which, dt)
 
     class _DisjointTimer(_Timer):
         """Time a phase EXCLUDING nested work that times itself into
@@ -127,9 +113,7 @@ class CostStats:
                     time_positive=self.time_positive,
                     time_negative=self.time_negative,
                     time_total=self.time_metadata + self.time_positive
-                    + self.time_negative,
-                    phases={k: h.as_dict()
-                            for k, h in self.phase_hists.items()})
+                    + self.time_negative)
 
 
 # --------------------------------------------------------------------------

@@ -10,7 +10,8 @@ final table is dense — see :mod:`repro.core.executors`.)  Tables are the
 unit of account in the byte-budgeted :class:`~repro.core.cache.CtCache`.
 
 ``nnz_rows`` reports the sparse-equivalent row count so benchmarks can be
-compared against the paper's Table 5 numbers.
+compared against the paper's Table 5 numbers.  Reads that block on the
+device go through :func:`to_host`, which spans them as ``host.read``.
 """
 
 from __future__ import annotations
@@ -21,7 +22,24 @@ from typing import Iterable, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import NULL_TRACER, NullTracer
 from .variables import CtVar
+
+
+def to_host(value, tracer: NullTracer = NULL_TRACER,
+            site: str = "") -> np.ndarray:
+    """Block on a device array and copy it to the host, inside a
+    ``host.read`` span carrying the bytes read and the calling ``site``.
+
+    Usage::
+
+        n = int(to_host(jnp.count_nonzero(x), tracer, "nnz_rows"))
+    """
+    with tracer.span("host.read") as sp:
+        out = np.asarray(value)
+        if tracer.enabled:
+            sp.set(nbytes=int(out.nbytes), site=site)
+    return out
 
 
 @dataclass
@@ -44,12 +62,13 @@ class CtTable:
     def nbytes(self) -> int:
         return int(self.counts.nbytes)
 
-    def nnz_rows(self) -> int:
+    def nnz_rows(self, tracer: NullTracer = NULL_TRACER) -> int:
         """Sparse-equivalent number of ct-table rows (paper Table 5)."""
-        return int(jnp.count_nonzero(self.counts))
+        return int(to_host(jnp.count_nonzero(self.counts), tracer,
+                           "nnz_rows"))
 
-    def total(self) -> float:
-        return float(jnp.sum(self.counts))
+    def total(self, tracer: NullTracer = NULL_TRACER) -> float:
+        return float(to_host(jnp.sum(self.counts), tracer, "total"))
 
     # -- algebra ------------------------------------------------------------
     def axis_of(self, var: CtVar) -> int:

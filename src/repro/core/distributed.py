@@ -304,8 +304,15 @@ class ShardedSparseExecutor(SparseExecutor):
     def shard_rows(self, arr) -> jax.Array:
         """Place a row array (edge or entity rows, padded to a multiple of
         the rank count) split over ``axis``: the layout every sharded
-        primitive takes its inputs in."""
-        return jax.device_put(arr, self._row_sharding)
+        primitive takes its inputs in.  A host array's upload is a
+        ``host.stage`` span."""
+        if not isinstance(arr, np.ndarray):
+            return jax.device_put(arr, self._row_sharding)
+        tr = self.tracer
+        with tr.span("host.stage") as sp:
+            if tr.enabled:
+                sp.set(nbytes=int(arr.nbytes))
+            return jax.device_put(arr, self._row_sharding)
 
     # -- shard_map closure cache --------------------------------------------
     def _shard_fn(self, key: Tuple, build):

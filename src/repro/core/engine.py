@@ -63,7 +63,6 @@ from typing import (Dict, FrozenSet, Hashable, List, Optional, Sequence, Set,
 
 import jax.numpy as jnp
 
-from ..obs.trace import NULL_TRACER
 from .cache import CtCache
 from .contract import CostStats
 from .ct import CtTable
@@ -155,16 +154,19 @@ class CountingEngine:
         self.stats = stats if stats is not None else CostStats()
         self.executor: Executor = (executor if isinstance(executor, Executor)
                                    else make_executor(executor, dtype=dtype))
+        # the request tracer follows the executor, the long-lived object
+        # CountingService.set_tracer wires, so an engine built on it (a
+        # strategy's pre-count) is traced from its first contraction
+        self.tracer = self.executor.tracer
         self.cache = cache if cache is not None else CtCache(
             cache_budget_bytes, self.stats)
+        if cache is None:
+            self.cache.tracer = self.tracer
         # freshness stamps: every entry records the relations it depends on
         # (derived from the key, so no call-site changes) and the store
         # version it was computed under
         self.cache.deps_fn = key_deps
         self.cache.version_fn = lambda: self.db.version
-        # request tracer (NULL_TRACER is free); CountingService.set_tracer
-        # wires a real one through engine + executor + cache together
-        self.tracer = NULL_TRACER
         self.dtype = dtype
         # one rows-counted set per engine: policies AND the counting
         # service share artefact key namespaces ("pos"/"full"/...), so
@@ -179,7 +181,7 @@ class CountingEngine:
     def count_rows_once(self, key: Tuple, tab: CtTable) -> None:
         if key not in self.rows_counted:
             self.rows_counted.add(key)
-            self.stats.ct_rows += tab.nnz_rows()
+            self.stats.ct_rows += tab.nnz_rows(self.tracer)
 
     def plan(self, point: LatticePoint,
              keep: Optional[Sequence[CtVar]] = None) -> ContractionPlan:
@@ -193,9 +195,14 @@ class CountingEngine:
 
     def contract(self, point: LatticePoint,
                  keep: Optional[Sequence[CtVar]] = None) -> CtTable:
-        """Positive ct-table straight from the data (counts as JOIN work)."""
-        return self.executor.positive(self.db, self.plan(point, keep),
-                                      self.stats)
+        """Positive ct-table straight from the data (counts as JOIN work),
+        inside a ``count.positive`` span of one table."""
+        tr = self.tracer
+        with tr.span("count.positive") as sp:
+            if tr.enabled:
+                sp.set(tables=1)
+            return self.executor.positive(self.db, self.plan(point, keep),
+                                          self.stats)
 
     def hist(self, var: Var, keep: Tuple[CtVar, ...]) -> CtTable:
         key = ("hist", self.executor.name, var, tuple(keep))

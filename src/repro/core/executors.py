@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.profile import annotate
 from ..obs.trace import NULL_TRACER
 from .contract import CostStats, _khatri_rao_reduce, _onehot
 from .ct import CtTable
@@ -110,6 +109,15 @@ class Executor:
         # real one is wired in by CountingService.set_tracer
         self.tracer = NULL_TRACER
 
+    def _stage(self, host: np.ndarray) -> jnp.ndarray:
+        """Upload one host array to the device, inside a ``host.stage``
+        span carrying its bytes."""
+        tr = self.tracer
+        with tr.span("host.stage") as sp:
+            if tr.enabled:
+                sp.set(nbytes=int(host.nbytes))
+            return jnp.asarray(host)
+
     # -- negative phase -----------------------------------------------------
     def mobius(self, stack: jnp.ndarray, k: int) -> jnp.ndarray:
         """Superset Möbius transform over the leading ``k`` binary axes —
@@ -166,8 +174,7 @@ class Executor:
             fn = self._batch_cache[key] = jax.jit(run)
         batch = jnp.stack(stacks + [stacks[0]] * (b_pad - b))
         with self.tracer.span("exec.mobius_batch", stacks=b, k=k,
-                              b_pad=b_pad), \
-                annotate("exec.mobius_batch"):
+                              b_pad=b_pad):
             out = fn(batch)
         return [out[i] for i in range(b)]
 
@@ -236,8 +243,7 @@ class Executor:
         for bs in [block_lists[0]] * (b_pad - b):        # pad: replay query 0
             flat.extend(bs)
         with self.tracer.span("exec.mobius_batch_fused", stacks=b, k=k,
-                              b_pad=b_pad), \
-                annotate("exec.mobius_batch_fused"):
+                              b_pad=b_pad):
             outs = fn(*flat)
         return list(outs[:b])
 
@@ -336,8 +342,7 @@ class Executor:
         fn = self._stacked_fn(db, template, b_pad,
                               t_layout if fused else None)
         with self.tracer.span("exec.positive_batch", plans=b, b_pad=b_pad,
-                              fused=fused), \
-                annotate("exec.positive_batch"):
+                              fused=fused):
             rows = fn(*stacked)                   # drops the pad rows
         out: List[CtTable] = []
         for plan, row in zip(plans, rows):
@@ -374,7 +379,7 @@ class Executor:
             return hit[2]
         packs = [plan_input_arrays(db, p) for p in plans]
         packs = packs + [packs[0]] * (b_pad - len(plans))
-        stacked = tuple(jnp.asarray(np.stack([p[j] for p in packs]))
+        stacked = tuple(self._stage(np.stack([p[j] for p in packs]))
                         for j in range(len(packs[0])))
         self._trim_input_cache()
         self._batch_cache[in_key] = (db, list(plans), stacked)
@@ -504,8 +509,7 @@ class Executor:
                               t_layout if fused else None)
         with self.tracer.span("exec.positive_batch_multi", plans=b,
                               b_pad=b_pad, fused=fused,
-                              dbs=len({id(d) for d in dbs})), \
-                annotate("exec.positive_batch_multi"):
+                              dbs=len({id(d) for d in dbs})):
             rows = fn(*stacked)
         out: List[CtTable] = []
         for db, plan, row, stats in zip(dbs, plans, rows, stats_list):
@@ -538,7 +542,7 @@ class Executor:
             return hit[2]
         packs = [plan_input_arrays(db, p) for db, p in zip(dbs, plans)]
         packs = packs + [packs[0]] * (b_pad - len(plans))
-        stacked = tuple(jnp.asarray(np.stack([p[j] for p in packs]))
+        stacked = tuple(self._stage(np.stack([p[j] for p in packs]))
                         for j in range(len(packs[0])))
         self._trim_input_cache()
         self._batch_cache[in_key] = (list(dbs), list(plans), stacked)
@@ -653,7 +657,7 @@ class Executor:
             packs = [fanout_input_arrays(dbs, p, partitioned)
                      for p in plans]
             packs = packs + [packs[0]] * (b_pad - m)
-            stacked = tuple(jnp.asarray(np.stack([p[j] for p in packs]))
+            stacked = tuple(self._stage(np.stack([p[j] for p in packs]))
                             for j in range(len(packs[0])))
             self._trim_input_cache()
             self._batch_cache[in_key] = (tuple(dbs), None, list(plans),
@@ -964,7 +968,7 @@ class DenseExecutor(Executor):
         msg = jnp.ones((tab.size, 1), dtype=self.dtype)
         mvars: List[CtVar] = []
         for cv in fs.attrs:
-            hot = _onehot(jnp.asarray(tab.attrs[cv.owner[1]]), cv.card,
+            hot = _onehot(self._stage(tab.attrs[cv.owner[1]]), cv.card,
                           self.dtype)
             n, d = msg.shape
             msg = (msg[:, :, None] * hot[:, None, :]).reshape(n, d * cv.card)
@@ -976,15 +980,15 @@ class DenseExecutor(Executor):
              ) -> Tuple[jnp.ndarray, List[CtVar]]:
         rt, gather_idx, scatter_idx, n_parent = _hop_indices(
             db, hop.atom, hop.child, hop.parent)
-        m = child_msg[jnp.asarray(gather_idx)]            # (edges, D)
+        m = child_msg[self._stage(gather_idx)]            # (edges, D)
         mvars = list(child_vars)
         for cv in hop.edge_attrs:
-            hot = _onehot(jnp.asarray(rt.attrs[cv.owner[1]]), cv.card,
+            hot = _onehot(self._stage(rt.attrs[cv.owner[1]]), cv.card,
                           self.dtype)                     # card+1, NA empty
             n, d = m.shape
             m = (m[:, :, None] * hot[:, None, :]).reshape(n, d * cv.card)
             mvars.append(cv)
-        out = jax.ops.segment_sum(m, jnp.asarray(scatter_idx),
+        out = jax.ops.segment_sum(m, self._stage(scatter_idx),
                                   num_segments=n_parent)
         if stats is not None:
             stats.joins += 1
@@ -1202,7 +1206,7 @@ class SparseExecutor(Executor):
             out = flat.reshape(n_parent, ds)
             out_vars = svars
         else:
-            rows = msg.dense[jnp.asarray(gather_np)]       # (edges, Dd)
+            rows = msg.dense[self._stage(gather_np)]       # (edges, Dd)
             agg = self._edge_segment_sum(seg_np, rows, total)
             out = agg.reshape(n_parent, ds * msg.dense.shape[1])
             out_vars = svars + tuple(msg.dvars)
@@ -1229,7 +1233,7 @@ class SparseExecutor(Executor):
         the Pallas kernel (:mod:`repro.kernels.segsum_kernel`) with
         ``interpret`` resolved by the same backend probe — Mosaic on
         TPU, Triton on GPU, the interpreter on CPU."""
-        seg = jnp.asarray(seg_np)
+        seg = self._stage(seg_np)
         if _segsum_kernel_enabled(total):
             from ..kernels import ops as kernel_ops
             if rows is None:
@@ -1290,8 +1294,10 @@ class SparseExecutor(Executor):
         expansion would not fit)."""
         if code is None:
             code = jnp.zeros((n,), dtype=jnp.int32)
+        elif isinstance(code, np.ndarray):
+            code = self._stage(code)
         if not factors:
-            return self._ones_segment_sum(jnp.asarray(code), ds)
+            return self._ones_segment_sum(code, ds)
         if len(factors) == 1:
             return jax.ops.segment_sum(factors[0], code,
                                        num_segments=ds).reshape(-1)

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import NULL_TRACER, NullTracer
 from .contract import CostStats
 from .ct import CtTable, scalar_table
 from .variables import (Atom, CtVar, LatticePoint, Var, connected_components,
@@ -314,9 +315,10 @@ def complete_ct(point: LatticePoint, keep: Sequence[CtVar],
                 provider: PositiveProvider,
                 stats: Optional[CostStats] = None,
                 use_butterfly: bool = True,
-                mobius_fn: Optional[Callable[[jnp.ndarray, int], jnp.ndarray]] = None
-                ) -> CtTable:
-    """Complete ct-table over ``keep`` — the Möbius Join.
+                mobius_fn: Optional[Callable[[jnp.ndarray, int], jnp.ndarray]] = None,
+                tracer: NullTracer = NULL_TRACER) -> CtTable:
+    """Complete ct-table over ``keep`` — the Möbius Join, inside a
+    ``count.negative`` span of one table on ``tracer``.
 
     ``keep`` may contain entity-attr axes, edge-attr axes, and relationship
     indicator axes of the point.  Relations with neither a kept indicator nor
@@ -324,6 +326,18 @@ def complete_ct(point: LatticePoint, keep: Sequence[CtVar],
     out, so they are dropped from the pattern up front (this is what makes
     HYBRID's per-family tables small).
     """
+    with tracer.span("count.negative") as sp:
+        if tracer.enabled:
+            sp.set(tables=1)
+        return _complete_ct(point, keep, provider, stats, use_butterfly,
+                            mobius_fn)
+
+
+def _complete_ct(point: LatticePoint, keep: Sequence[CtVar],
+                 provider: PositiveProvider,
+                 stats: Optional[CostStats], use_butterfly: bool,
+                 mobius_fn: Optional[Callable[[jnp.ndarray, int],
+                                              jnp.ndarray]]) -> CtTable:
     keep = tuple(keep)
     kept_attrs = tuple(v for v in keep if v.kind == "attr")
     kept_edges: Dict[str, List[CtVar]] = {}
@@ -416,9 +430,11 @@ def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
                      mobius_fused_fn: Optional[Callable[
                          [Sequence[Sequence[jnp.ndarray]], int,
                           Tuple[int, ...]],
-                         List[jnp.ndarray]]] = None) -> List[CtTable]:
+                         List[jnp.ndarray]]] = None,
+                     tracer: NullTracer = NULL_TRACER) -> List[CtTable]:
     """Complete ct-tables for many ``(point, keep)`` queries, with the
-    Möbius negative phase batched across same-shape butterfly stacks.
+    Möbius negative phase batched across same-shape butterfly stacks, all
+    inside one ``count.negative`` span on ``tracer``.
 
     Butterfly-eligible queries (no kept edge-attr axes, ``k > 0``) are
     grouped — same-signature families are same-shape by construction —
@@ -448,6 +464,7 @@ def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
         mobius_fused_fn: fused batched transform ``(block_lists, k, perm)
             -> [table array]``; preferred over ``mobius_batch_fn`` when
             given.
+        tracer: the request tracer of the engine behind ``provider``.
 
     Returns:
         One :class:`~repro.core.ct.CtTable` per query, positionally
@@ -460,6 +477,15 @@ def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
                                 mobius_fused_fn=executor.mobius_batch_fused)
     """
     queries = [(point, tuple(keep)) for point, keep in queries]
+    with tracer.span("count.negative") as sp:
+        if tracer.enabled:
+            sp.set(tables=len(queries))
+        return _complete_ct_many(queries, provider, stats, use_butterfly,
+                                 mobius_fn, mobius_batch_fn, mobius_fused_fn)
+
+
+def _complete_ct_many(queries, provider, stats, use_butterfly, mobius_fn,
+                      mobius_batch_fn, mobius_fused_fn) -> List[CtTable]:
     if mobius_batch_fn is None:
         mobius_batch_fn = lambda stacks, k: butterfly_batch(
             stacks, k, mobius_fn)
@@ -469,9 +495,8 @@ def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
     for i, (point, keep) in enumerate(queries):
         bp = _butterfly_plan(point, keep) if use_butterfly else None
         if bp is None:
-            results[i] = complete_ct(point, keep, provider, stats,
-                                     use_butterfly=use_butterfly,
-                                     mobius_fn=mobius_fn)
+            results[i] = _complete_ct(point, keep, provider, stats,
+                                      use_butterfly, mobius_fn)
         else:
             eligible.append((i, bp,
                              _butterfly_blocks(point, bp, provider, memo)))

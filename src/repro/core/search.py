@@ -38,9 +38,10 @@ from typing import (Callable, Dict, FrozenSet, Iterable, List, MutableMapping,
                     Optional, Sequence, Set, Tuple)
 
 import jax.numpy as jnp
-import numpy as np
 
+from ..obs.trace import NULL_TRACER
 from .bdeu import bdeu_score_batch, family_nijk, family_score
+from .ct import to_host
 from .database import RelationalDB
 from .strategies import Strategy
 from .variables import CtVar, LatticePoint, build_lattice
@@ -116,6 +117,8 @@ class StructureSearch:
         self.family_deps: Dict[Family, FrozenSet[str]] = {}
         self.families_scored = 0
         self.batch_calls = 0          # vmapped BDeu dispatches issued
+        # the count provider's request tracer: score reads are host.read
+        self.tracer = getattr(self.counts, "tracer", None) or NULL_TRACER
 
     # -- family scoring (through the counting strategy) ---------------------
     def local_score(self, point: LatticePoint, child: CtVar,
@@ -124,7 +127,8 @@ class StructureSearch:
         if key not in self._score_cache:
             keep = tuple(sorted(parents)) + (child,)
             tab = self.counts.family_ct(point, keep)
-            self._score_cache[key] = family_score(tab, child, self.ess)
+            self._score_cache[key] = family_score(tab, child, self.ess,
+                                                  tracer=self.tracer)
             self.family_deps[key] = point.rels
             self.families_scored += 1
         return self._score_cache[key]
@@ -165,7 +169,8 @@ class StructureSearch:
             b_pad = 1 << max(b - 1, 0).bit_length()
             if b_pad != b:
                 stack = jnp.pad(stack, ((0, b_pad - b), (0, 0), (0, 0)))
-            scores = np.asarray(bdeu_score_batch(stack, ess=self.ess))[:b]
+            scores = to_host(bdeu_score_batch(stack, ess=self.ess),
+                             self.tracer, "search.batch_scores")[:b]
             self.batch_calls += 1
             for (fam, _), s in zip(members, scores):
                 self._score_cache[fam] = float(s)

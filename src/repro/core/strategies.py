@@ -79,28 +79,35 @@ class Strategy:
     # -- pre-search phase ----------------------------------------------------
     def prepare(self, db: RelationalDB,
                 lattice: Sequence[LatticePoint]) -> None:
-        self.db, self.lattice = db, list(lattice)
-        with self.stats.timer("metadata"):
-            from .executors import make_executor
-            ex = (self.executor if not isinstance(self.executor, str)
-                  else make_executor(self.executor, dtype=self.dtype,
-                                     use_pallas_mobius=self.use_pallas_mobius))
-            self.engine = CountingEngine(
-                db, ex, self.stats,
-                cache_budget_bytes=self.cache_budget_bytes, dtype=self.dtype)
-            self.provider = self._policy_cls(self.engine)
-            self._service = None           # rebuilt lazily over this engine
-            self._rows_counted = set()
-            if self._warm_hists:
+        """The pre-search phase, inside one ``strategy.prepare`` span on
+        the executor's tracer."""
+        from .executors import make_executor
+        ex = (self.executor if not isinstance(self.executor, str)
+              else make_executor(self.executor, dtype=self.dtype,
+                                 use_pallas_mobius=self.use_pallas_mobius))
+        tr = ex.tracer
+        with tr.span("strategy.prepare") as sp:
+            if tr.enabled:
+                sp.set(strategy=self.name)
+            self.db, self.lattice = db, list(lattice)
+            with self.stats.timer("metadata"):
+                self.engine = CountingEngine(
+                    db, ex, self.stats,
+                    cache_budget_bytes=self.cache_budget_bytes,
+                    dtype=self.dtype)
+                self.provider = self._policy_cls(self.engine)
+                self._service = None       # rebuilt lazily over this engine
+                self._rows_counted = set()
+                if self._warm_hists:
+                    for point in lattice:
+                        for v in point.vars:
+                            self.provider.hist(v, ())
+            # data access inside the policy times itself (-> time_positive),
+            # including any eviction-driven recompute later on
+            self.provider.precompute(lattice)
+            if self._precount_complete:
                 for point in lattice:
-                    for v in point.vars:
-                        self.provider.hist(v, ())
-        # data access inside the policy times itself (-> time_positive),
-        # including any eviction-driven recompute later on
-        self.provider.precompute(lattice)
-        if self._precount_complete:
-            for point in lattice:
-                self._complete_full(point)
+                    self._complete_full(point)
 
     # -- complete tables -----------------------------------------------------
     def _mobius_fn(self):
@@ -116,7 +123,8 @@ class Strategy:
         with self.stats.disjoint_timer("negative"):
             return complete_ct(point, keep, self.provider, self.stats,
                                use_butterfly=self.use_butterfly,
-                               mobius_fn=self._mobius_fn())
+                               mobius_fn=self._mobius_fn(),
+                               tracer=self.engine.tracer)
 
     def _complete_full(self, point: LatticePoint) -> CtTable:
         """Complete (positive+negative) table over *all* axes of a point —
@@ -130,7 +138,7 @@ class Strategy:
             hit = self._timed_complete(point, keep)
             if key not in self._rows_counted:    # once per point, not per
                 self._rows_counted.add(key)      # eviction recompute
-                self.stats.ct_rows += hit.nnz_rows()
+                self.stats.ct_rows += hit.nnz_rows(self.engine.tracer)
             self.engine.cache.put(key, hit)
         return hit
 
@@ -230,7 +238,8 @@ class Strategy:
                     self.stats, use_butterfly=self.use_butterfly,
                     mobius_fn=self._mobius_fn(),
                     mobius_batch_fn=self._mobius_batch_fn(),
-                    mobius_fused_fn=self._mobius_fused_fn())
+                    mobius_fused_fn=self._mobius_fused_fn(),
+                    tracer=self.engine.tracer)
             for keep, tab in zip(missing, tabs):
                 cache.put(("fam",) + _freeze(point, keep), tab)
                 fresh[keep] = tab      # return directly: under a tight

@@ -8,8 +8,8 @@ Three pieces, each usable alone:
   whose merge is exactly associative (p50/p95/p99 + max);
 * :mod:`repro.obs.registry` — Prometheus-text / JSON rendering of
   snapshots, plus :mod:`repro.obs.slowlog` (top-K slow queries) and
-  :mod:`repro.obs.profile` (``jax.profiler`` annotations on jitted
-  dispatches).
+  :mod:`repro.obs.profile` (``jax.profiler`` annotations: every live
+  span is one while they are enabled).
 
 This package deliberately imports nothing from :mod:`repro.core` or
 :mod:`repro.serve`, so every layer of the stack can depend on it.

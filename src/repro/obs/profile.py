@@ -2,10 +2,11 @@
 
 Host-side spans (:mod:`repro.obs.trace`) stop at the jit boundary — the
 device timeline in a ``jax.profiler`` trace shows XLA op names, not
-"which bucket dispatch was this".  Wrapping each jitted dispatch in a
-``jax.profiler.TraceAnnotation`` with the *same name the span uses*
-("exec.positive_batch", "exec.mobius_batch") makes the two timelines
-joinable by eye in TensorBoard / Perfetto.
+"which phase of the program was this".  While annotations are enabled,
+every live :class:`~repro.obs.trace.Span` opens a
+``jax.profiler.TraceAnnotation`` of its own name (see
+:meth:`repro.obs.trace.Span.__enter__`), so the trace's host plane holds
+the program's span tree on the same clock as the device planes.
 
 Annotations are off by default (they cost a C++ call even when no
 profiler session is active) and enabled process-wide via
@@ -65,17 +66,18 @@ def enabled() -> bool:
     return _enabled
 
 
-def annotate(name: str):
-    """A context manager marking ``name`` on the device profile timeline
-    when enabled, or a shared no-op otherwise.
+def annotate(name: str, **stats):
+    """A context manager marking ``name`` on the profile's host timeline
+    when enabled, or a shared no-op otherwise.  ``stats`` become the
+    event's stats in the trace.
 
     Usage::
 
-        with annotate("exec.positive_batch"):
+        with annotate("probe", step=3):
             out = jitted_fn(batch)
     """
     if _enabled and _trace_annotation:
-        return _trace_annotation(name)
+        return _trace_annotation(name, **stats)
     return _NULL
 
 

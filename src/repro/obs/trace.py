@@ -28,6 +28,14 @@ Design constraints, in order:
 * **Retroactive spans.**  Queue residency is only known when the entry is
   drained; :meth:`Tracer.record` writes a span from timestamps captured
   earlier, so no span object needs to live across threads.
+* **One clock with the device.**  While :mod:`repro.obs.profile`
+  annotations are enabled, every live :class:`Span` also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace's
+  host plane carries the program's span tree beside the device planes.
+  The annotation carries one stat, ``repro_span`` (:data:`PROGRAM_SPAN_STAT`),
+  whose value is the span's id: a trace reader tells program spans from
+  JAX's own host events by that stat alone, and joins them to the ring's
+  :class:`SpanRecord` by id.  Retroactive spans and events open none.
 
 Enable per service/router via the ``tracer=`` knob (or
 ``CountingService.set_tracer`` / ``CountingRouter.set_tracer``), or
@@ -53,10 +61,16 @@ import time
 from collections import deque
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
+from . import profile as _profile
 from .slowlog import SlowQueryLog
 
 __all__ = ["SpanContext", "SpanRecord", "Span", "Tracer", "NullTracer",
-           "NULL_TRACER", "default_tracer", "build_trees"]
+           "NULL_TRACER", "PROGRAM_SPAN_STAT", "default_tracer",
+           "build_trees"]
+
+# the stat that marks a profiler annotation as a program span; its value
+# is the span id
+PROGRAM_SPAN_STAT = "repro_span"
 
 _ids = itertools.count(1)          # span ids; next() is atomic in CPython
 _trace_ids = itertools.count(1)
@@ -126,10 +140,12 @@ NULL_SPAN = _NullSpan()
 class Span:
     """A live span (context manager).  Created by :meth:`Tracer.span`;
     the record is appended to the ring on ``__exit__`` — which the
-    ``with`` statement guarantees, so every started span closes."""
+    ``with`` statement guarantees, so every started span closes.  While
+    profiler annotations are enabled the span is also a
+    ``TraceAnnotation`` (see the module docstring)."""
 
     __slots__ = ("_tracer", "name", "attrs", "trace_id", "span_id",
-                 "parent_id", "t0", "t1", "_pushed")
+                 "parent_id", "t0", "t1", "_pushed", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: Optional[SpanContext], attrs: dict):
@@ -146,6 +162,7 @@ class Span:
                 self.trace_id, self.parent_id = next(_trace_ids), None
         self.t0 = self.t1 = 0.0
         self._pushed = False
+        self._ann = None
 
     @property
     def context(self) -> SpanContext:
@@ -159,11 +176,18 @@ class Span:
     def __enter__(self) -> "Span":
         self._tracer._push(self)
         self._pushed = True
+        if _profile.enabled():
+            self._ann = _profile.annotate(
+                self.name, **{PROGRAM_SPAN_STAT: self.span_id})
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         if self._pushed:
             self._tracer._pop(self)
         self._tracer._append(SpanRecord(

@@ -172,9 +172,10 @@ def execute_bucketed(executor: Executor, db: RelationalDB,
             signature bucket).
         metrics: optional :class:`~repro.serve.metrics.ServiceMetrics`
             that receives one ``observe_batch`` per micro-batch.
-        tracer: optional :class:`~repro.obs.trace.Tracer`; each
-            micro-batch dispatch becomes a ``batch.dispatch`` span
-            (nested under whatever span is open on this thread).
+        tracer: optional :class:`~repro.obs.trace.Tracer`; the whole
+            call is one ``count.positive`` span (``tables``: the plans
+            contracted) and each micro-batch dispatch a ``batch.dispatch``
+            span inside it.
 
     Returns:
         One :class:`~repro.core.ct.CtTable` per plan, in input order.
@@ -183,28 +184,26 @@ def execute_bucketed(executor: Executor, db: RelationalDB,
 
         tabs = execute_bucketed(engine.executor, db, plans, engine.stats)
     """
-    results: List[Optional[CtTable]] = [None] * len(plans)
-    for sig, idxs in group_by_signature(plans, key="shape").items():
-        step = max_batch_size if max_batch_size else len(idxs)
-        for s in range(0, len(idxs), max(step, 1)):
-            chunk = idxs[s:s + max(step, 1)]
-            span = (tracer.span("batch.dispatch", sig=sig,
-                                queries=len(chunk))
-                    if tracer.enabled else None)
-            t0 = time.perf_counter()
-            if span is not None:
-                with span:
+    with tracer.span("count.positive") as sp:
+        if tracer.enabled:
+            sp.set(tables=len(plans))
+        results: List[Optional[CtTable]] = [None] * len(plans)
+        for sig, idxs in group_by_signature(plans, key="shape").items():
+            step = max_batch_size if max_batch_size else len(idxs)
+            for s in range(0, len(idxs), max(step, 1)):
+                chunk = idxs[s:s + max(step, 1)]
+                t0 = time.perf_counter()
+                with (tracer.span("batch.dispatch", sig=sig,
+                                  queries=len(chunk))
+                      if tracer.enabled else nullcontext()):
                     tabs = executor.positive_batch(
                         db, [plans[i] for i in chunk], stats)
-            else:
-                tabs = executor.positive_batch(db, [plans[i] for i in chunk],
-                                               stats)
-            dt = time.perf_counter() - t0
-            if metrics is not None:
-                metrics.observe_batch(sig, len(chunk), dt)
-            for i, tab in zip(chunk, tabs):
-                results[i] = tab
-    return results
+                dt = time.perf_counter() - t0
+                if metrics is not None:
+                    metrics.observe_batch(sig, len(chunk), dt)
+                for i, tab in zip(chunk, tabs):
+                    results[i] = tab
+        return results
 
 
 def execute_bucketed_multi(executor: Executor,
@@ -237,7 +236,8 @@ def execute_bucketed_multi(executor: Executor,
             :class:`~repro.serve.metrics.ServiceMetrics`; each distinct
             instance in a micro-batch receives one ``observe_batch`` with
             its own query count and its wall-time share of the dispatch.
-        tracer: optional tracer; each micro-batch becomes a
+        tracer: optional tracer; the whole call is one
+            ``count.positive`` span and each micro-batch a
             ``batch.dispatch`` span carrying the tenant fan-in.
 
     Returns:
@@ -247,39 +247,38 @@ def execute_bucketed_multi(executor: Executor,
 
         tabs = execute_bucketed_multi(executor, dbs, plans)
     """
-    results: List[Optional[CtTable]] = [None] * len(plans)
-    for sig, idxs in group_by_signature(plans, key="shape").items():
-        step = max_batch_size if max_batch_size else len(idxs)
-        for s in range(0, len(idxs), max(step, 1)):
-            chunk = idxs[s:s + max(step, 1)]
-            c_dbs = [dbs[i] for i in chunk]
-            c_plans = [plans[i] for i in chunk]
-            c_stats = ([stats_list[i] for i in chunk]
-                       if stats_list is not None else None)
-            span = (tracer.span("batch.dispatch", sig=sig,
-                                queries=len(chunk),
-                                dbs=len({id(d) for d in c_dbs}))
-                    if tracer.enabled else None)
-            t0 = time.perf_counter()
-            if span is not None:
-                with span:
+    with tracer.span("count.positive") as sp:
+        if tracer.enabled:
+            sp.set(tables=len(plans))
+        results: List[Optional[CtTable]] = [None] * len(plans)
+        for sig, idxs in group_by_signature(plans, key="shape").items():
+            step = max_batch_size if max_batch_size else len(idxs)
+            for s in range(0, len(idxs), max(step, 1)):
+                chunk = idxs[s:s + max(step, 1)]
+                c_dbs = [dbs[i] for i in chunk]
+                c_plans = [plans[i] for i in chunk]
+                c_stats = ([stats_list[i] for i in chunk]
+                           if stats_list is not None else None)
+                t0 = time.perf_counter()
+                with (tracer.span("batch.dispatch", sig=sig,
+                                  queries=len(chunk),
+                                  dbs=len({id(d) for d in c_dbs}))
+                      if tracer.enabled else nullcontext()):
                     tabs = executor.positive_batch_multi(c_dbs, c_plans,
                                                          c_stats)
-            else:
-                tabs = executor.positive_batch_multi(c_dbs, c_plans, c_stats)
-            dt = time.perf_counter() - t0
-            if metrics_list is not None:
-                shares: Dict[int, Tuple[ServiceMetrics, int]] = {}
-                for i in chunk:
-                    m = metrics_list[i]
-                    if m is not None:
-                        _, n = shares.get(id(m), (m, 0))
-                        shares[id(m)] = (m, n + 1)
-                for m, n in shares.values():
-                    m.observe_batch(sig, n, dt * n / len(chunk))
-            for i, tab in zip(chunk, tabs):
-                results[i] = tab
-    return results
+                dt = time.perf_counter() - t0
+                if metrics_list is not None:
+                    shares: Dict[int, Tuple[ServiceMetrics, int]] = {}
+                    for i in chunk:
+                        m = metrics_list[i]
+                        if m is not None:
+                            _, n = shares.get(id(m), (m, 0))
+                            shares[id(m)] = (m, n + 1)
+                    for m, n in shares.values():
+                        m.observe_batch(sig, n, dt * n / len(chunk))
+                for i, tab in zip(chunk, tabs):
+                    results[i] = tab
+        return results
 
 
 def execute_complete_bucketed(engine: CountingEngine, policy,
@@ -370,4 +369,4 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
         return complete_ct_many(queries, policy, stats,
                                 use_butterfly=use_butterfly,
                                 mobius_fn=engine.mobius_fn(),
-                                mobius_fused_fn=fused_fn)
+                                mobius_fused_fn=fused_fn, tracer=tracer)

@@ -589,8 +589,11 @@ class CountingRouter:
                         continue
                     gplans = [p for p, _ in live]
                     t0 = time.perf_counter()
-                    merged = ex0.positive_fanout_merged(
-                        dbs, gplans, sdb.partitioned, stats)
+                    with self.tracer.span("count.positive") as sp:
+                        if self.tracer.enabled:
+                            sp.set(tables=len(gplans))
+                        merged = ex0.positive_fanout_merged(
+                            dbs, gplans, sdb.partitioned, stats)
                     dt = time.perf_counter() - t0
                     for (_, key), tab in zip(live, merged):
                         self._settle(key, tab, epoch)
@@ -705,6 +708,10 @@ class CountingRouter:
                 with ExitStack() as timers:
                     for eng in engines:
                         timers.enter_context(eng.stats.timer("positive"))
+                    sp = timers.enter_context(
+                        self.tracer.span("count.positive"))
+                    if self.tracer.enabled:
+                        sp.set(tables=len(plans))
                     per_shard, merged = ex0.positive_stacked_merged(
                         dbs, exs, plans, stats)
                 dt = time.perf_counter() - t0
@@ -911,7 +918,8 @@ class CountingRouter:
                 [norm[i] for i in todo], provider,
                 use_butterfly=True,
                 mobius_fn=engines[0].mobius_fn(),
-                mobius_fused_fn=engines[0].mobius_fused_fn())
+                mobius_fused_fn=engines[0].mobius_fused_fn(),
+                tracer=self.tracer)
             for i, tab in zip(todo, tabs):
                 point, keep = norm[i]
                 self._settle(("complete", point.atoms, keep), tab, epoch)
