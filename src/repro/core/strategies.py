@@ -159,11 +159,16 @@ class Strategy:
     def service(self):
         """Lazy per-strategy :class:`~repro.serve.service.CountingService`
         over the shared engine — the batching front-end for this
-        strategy's positive contractions."""
+        strategy's positive contractions.  Its complete-CT queries read
+        positives through this strategy's own policy, as
+        :meth:`family_ct_many` does: HYBRID and PRECOUNT project the
+        pre-counted ``"full"`` tables, ONDEMAND contracts from data,
+        TUPLEID recombines its messages."""
         svc = getattr(self, "_service", None)
         if svc is None:
             from ..serve.service import CountingService
-            svc = self._service = CountingService(self.engine)
+            svc = self._service = CountingService(self.engine,
+                                                  positives=self.provider)
         return svc
 
     def _mobius_batch_fn(self):

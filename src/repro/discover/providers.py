@@ -115,8 +115,9 @@ class ServiceCounts:
         return self.service.engine.db.schema
 
     def prepare(self, lattice: Sequence[LatticePoint]) -> None:
-        # The service's engine was planned at construction time; nothing
-        # per-lattice to build — completions are computed on demand.
+        # Nothing per-lattice to build: a strategy's service already holds
+        # its pre-count (Strategy.prepare), and a bare engine's service
+        # contracts each positive from data on first use.
         pass
 
     def version(self) -> Tuple:

@@ -303,6 +303,10 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
     identical to per-query :func:`~repro.core.mobius.complete_ct`.  Time
     accounting matches the strategy path: data access lands in
     ``time_positive``, the transform in ``time_negative`` (disjointly).
+    The call is one ``count.complete`` span on the engine's tracer,
+    carrying ``subqueries`` (the distinct positive sub-queries, by atoms
+    and keep, that the Möbius joins need) and ``from_data`` (the tables
+    ``policy`` contracted from data for them).
 
     Args:
         engine: the planner/executor/cache stack to execute against.
@@ -329,44 +333,48 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
     queries = [(point, tuple(keep)) for point, keep in queries]
     timer = ((lambda which: stats.timer(which)) if stats is not None
              else (lambda which: nullcontext()))
-    pos: List[Tuple[LatticePoint, Tuple[CtVar, ...]]] = []
-    for point, keep in queries:
-        pos.extend(positive_queries(point, keep, use_butterfly))
-    todo = policy.batchable_misses(pos)
     tracer = getattr(engine, "tracer", NULL_TRACER)
-    if todo:
-        plans = [engine.plan(p, k) for p, k in todo]
-        with timer("positive"):
-            tabs = execute_bucketed(engine.executor, engine.db, plans,
-                                    stats, max_batch_size, metrics,
-                                    tracer=tracer)
-        for (p, _), plan, tab in zip(todo, plans, tabs):
-            policy.absorb(p, plan.keep, tab)
+    with tracer.span("count.complete") as sp:
+        pos: List[Tuple[LatticePoint, Tuple[CtVar, ...]]] = []
+        for point, keep in queries:
+            pos.extend(positive_queries(point, keep, use_butterfly))
+        todo = policy.batchable_misses(pos)
+        if tracer.enabled:
+            sp.set(subqueries=len({(p.atoms, k) for p, k in pos}),
+                   from_data=len(todo))
+        if todo:
+            plans = [engine.plan(p, k) for p, k in todo]
+            with timer("positive"):
+                tabs = execute_bucketed(engine.executor, engine.db, plans,
+                                        stats, max_batch_size, metrics,
+                                        tracer=tracer)
+            for (p, _), plan, tab in zip(todo, plans, tabs):
+                policy.absorb(p, plan.keep, tab)
 
-    # the engine's fused evaluator always exists, so every
-    # butterfly-eligible query takes the fused path; blockwise queries
-    # fall back to per-query complete_ct over mobius_fn
-    fused_fn = engine.mobius_fused_fn()
-    if metrics is not None or tracer.enabled:
-        inner_fused = fused_fn
-        _metrics = metrics
+        # the engine's fused evaluator always exists, so every
+        # butterfly-eligible query takes the fused path; blockwise queries
+        # fall back to per-query complete_ct over mobius_fn
+        fused_fn = engine.mobius_fused_fn()
+        if metrics is not None or tracer.enabled:
+            inner_fused = fused_fn
+            _metrics = metrics
 
-        def fused_fn(blocks, k, perm):
-            with (tracer.span("mobius.dispatch", stacks=len(blocks), k=k)
-                  if tracer.enabled else nullcontext()):
-                t0 = time.perf_counter()
-                out = inner_fused(blocks, k, perm)
-                dt = time.perf_counter() - t0
-            if _metrics is not None:
-                _metrics.observe_mobius(len(blocks), dt)
-            return out
+            def fused_fn(blocks, k, perm):
+                with (tracer.span("mobius.dispatch", stacks=len(blocks), k=k)
+                      if tracer.enabled else nullcontext()):
+                    t0 = time.perf_counter()
+                    out = inner_fused(blocks, k, perm)
+                    dt = time.perf_counter() - t0
+                if _metrics is not None:
+                    _metrics.observe_mobius(len(blocks), dt)
+                return out
 
-    # any residual data access (unwarmed misses, eviction recomputes) times
-    # itself in the policy; the disjoint timer subtracts its growth to
-    # keep the Fig. 3 decomposition disjoint
-    with (stats.disjoint_timer("negative") if stats is not None
-          else nullcontext()):
-        return complete_ct_many(queries, policy, stats,
-                                use_butterfly=use_butterfly,
-                                mobius_fn=engine.mobius_fn(),
-                                mobius_fused_fn=fused_fn, tracer=tracer)
+        # any residual data access (unwarmed misses, eviction recomputes)
+        # times itself in the policy; the disjoint timer subtracts its
+        # growth to keep the Fig. 3 decomposition disjoint
+        with (stats.disjoint_timer("negative") if stats is not None
+              else nullcontext()):
+            return complete_ct_many(queries, policy, stats,
+                                    use_butterfly=use_butterfly,
+                                    mobius_fn=engine.mobius_fn(),
+                                    mobius_fused_fn=fused_fn, tracer=tracer)

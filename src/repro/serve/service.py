@@ -12,9 +12,12 @@ shared byte-budgeted :class:`~repro.core.cache.CtCache`:
   identical in-flight queries are coalesced onto one pending entry.
 * ``submit_complete(point, keep)`` queues a **complete-CT** query
   (positive + Möbius negative phase, ``keep`` may include relationship
-  indicator axes).  Complete queries ride the same scheduler; dispatch
-  batches their positive sub-queries in signature buckets AND their
-  negative-phase butterfly transforms in same-shape groups
+  indicator axes).  Complete queries ride the same scheduler; their
+  positive tables come from the service's positive policy (a strategy's
+  own, so HYBRID projects its pre-count; on a bare engine, contraction
+  from data), and dispatch batches what that policy must contract in
+  signature buckets AND the negative-phase butterfly transforms in
+  same-shape groups
   (:func:`~repro.serve.batching.execute_complete_bucketed`).
 * Pending queries are bucketed by
   :meth:`~repro.core.plan.ContractionPlan.shape_signature`.  A bucket is
@@ -58,7 +61,8 @@ import numpy as np
 
 from ..core.cache import DEFAULT_TENANT
 from ..core.ct import CtTable
-from ..core.engine import CountingEngine, DeltaReport, OnDemandPositives
+from ..core.engine import (CountingEngine, DeltaReport, OnDemandPositives,
+                           _Policy)
 from ..core.plan import ContractionPlan
 from ..core.variables import CtVar, LatticePoint
 from ..obs.trace import NullTracer, SpanContext, default_tracer
@@ -302,10 +306,18 @@ class CountingService:
             submits follow ``admission_policy``: ``"shed"`` raises
             :class:`TenantAdmissionError`, ``"queue"`` sleeps the
             flooding thread (off-lock) until a token accrues.
+        positives: the positive policy over ``engine`` that complete-CT
+            queries read their positive tables from.  A strategy hands
+            its own (:meth:`~repro.core.strategies.Strategy.service`), so
+            HYBRID and PRECOUNT project their pre-counted tables; the
+            default, for a bare engine with no pre-count, is an
+            :class:`~repro.core.engine.OnDemandPositives` that contracts
+            each positive from data on first use.
 
     Raises:
         ValueError: ``max_batch_size < 1``, an unknown
-            ``admission_policy``, or a non-positive ``rate_limit``.
+            ``admission_policy``, a non-positive ``rate_limit``, or
+            ``positives`` over another engine.
 
     Usage::
 
@@ -325,12 +337,16 @@ class CountingService:
                  tenant: str = DEFAULT_TENANT,
                  admission_max: Optional[int] = None,
                  admission_policy: str = "queue",
-                 rate_limit: Optional[Tuple[int, float]] = None):
+                 rate_limit: Optional[Tuple[int, float]] = None,
+                 positives: Optional[_Policy] = None):
         if max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
         if admission_policy not in ("queue", "shed"):
             raise ValueError(f"unknown admission_policy "
                              f"{admission_policy!r} (queue|shed)")
+        if positives is not None and positives.engine is not engine:
+            raise ValueError("positives must be a policy over the "
+                             "service's engine")
         self.engine = engine
         self.tenant = tenant
         self.admission_max = admission_max
@@ -355,7 +371,8 @@ class CountingService:
         self._pending: Dict[Tuple, _Pending] = {}
         self._by_sig: Dict[Tuple, List[Tuple]] = {}   # sig -> [req_key]
         self._pending_bytes = 0
-        self._policy: Optional[OnDemandPositives] = None  # complete-CT path
+        self._policy: _Policy = (positives if positives is not None
+                                 else OnDemandPositives(engine))
         self._dispatcher_thread: Optional[threading.Thread] = None
         self._shut_down = False
         self._defer_depth = 0          # see defer_drains()
@@ -1068,7 +1085,7 @@ class CountingService:
                 if completes:
                     t0 = time.perf_counter()
                     tabs = execute_complete_bucketed(
-                        eng, self._complete_policy(),
+                        eng, self._policy,
                         [(e.point, e.keep) for e in completes],
                         eng.stats, max_batch_size=self.max_batch_size,
                         metrics=self.metrics,
@@ -1130,14 +1147,6 @@ class CountingService:
         # same namespace as Strategy.family_ct: a search sharing this
         # engine is served straight from the warmed family cache
         return ("fam", point.atoms, tuple(keep))
-
-    def _complete_policy(self) -> OnDemandPositives:
-        """The positive policy backing complete-CT queries (lazy; shares
-        the engine's cache and row accounting with any co-resident
-        search)."""
-        if self._policy is None:
-            self._policy = OnDemandPositives(self.engine)
-        return self._policy
 
     def _estimate_bytes(self, plan: ContractionPlan) -> int:
         itemsize = np.dtype(self.engine.dtype).itemsize
