@@ -122,18 +122,15 @@ class RunRecord:
 
 def run_one(name: str, strategy_name: str, scale: Optional[float] = None,
             budget_s: float = TIME_BUDGET_S, seed: int = 0,
-            use_kernel_mobius: bool = False, executor: str = "dense",
+            executor: str = "dense",
             cache_budget_bytes: Optional[int] = None) -> RunRecord:
     scale = DEFAULT_SCALES[name] if scale is None else scale
     db = paper_benchmark_db(name, seed=seed, scale=scale)
     lattice = build_lattice(db.schema, max_length=2)
     work = family_workload(db, lattice)
 
-    kw = {"executor": executor, "cache_budget_bytes": cache_budget_bytes}
-    if use_kernel_mobius:
-        from repro.kernels.ops import mobius_nd
-        kw["mobius_fn"] = mobius_nd
-    strat = make_strategy(strategy_name, **kw)
+    strat = make_strategy(strategy_name, executor=executor,
+                          cache_budget_bytes=cache_budget_bytes)
 
     t0 = time.perf_counter()
     completed = True
@@ -553,9 +550,7 @@ def bench_negative_flood(n_rels: int = 16, edges: int = 2000,
 
         def per_family_round():
             eng.cache.evict_all()
-            jax.block_until_ready([complete_ct(p, k, policy,
-                                               mobius_fn=eng.mobius_fn()
-                                               ).counts
+            jax.block_until_ready([complete_ct(p, k, policy).counts
                                    for p, k in queries])
 
         per_family_round()

@@ -33,23 +33,6 @@ def _time(fn: Callable, *args, reps: int = 5) -> float:
     return (time.perf_counter() - t0) / reps * 1e6   # us
 
 
-def bench_mobius() -> List[dict]:
-    rows = []
-    key = jax.random.PRNGKey(0)
-    for k in (1, 2, 3, 4, 6):
-        for d in (128, 2048, 16384):
-            x = jax.random.uniform(key, (1 << k, d), jnp.float32) * 100
-            want = ref.mobius_ref(x)
-            got = ops.mobius(x, interpret=True)
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
-            us_ref = _time(jax.jit(ref.mobius_ref), x)
-            us_int = _time(lambda a: ops.mobius(a, interpret=True), x)
-            rows.append({"kernel": "mobius", "k": k, "d": d,
-                         "us_ref": round(us_ref, 1),
-                         "us_interpret": round(us_int, 1)})
-    return rows
-
-
 def bench_hist() -> List[dict]:
     rows = []
     key = jax.random.PRNGKey(1)
@@ -128,7 +111,7 @@ def bench_bdeu() -> List[dict]:
 
 def main(out_dir: str = "results/bench",
          bench_json: str = "BENCH_counting.json") -> List[dict]:
-    rows = bench_mobius() + bench_hist() + bench_segsum() + bench_bdeu()
+    rows = bench_hist() + bench_segsum() + bench_bdeu()
     for r in rows:
         print("[kernels] " + ",".join(f"{k}={v}" for k, v in r.items()),
               flush=True)

@@ -17,10 +17,11 @@ One chip:
    product of its entity counts, with its R=T slice equal to the positive
    table.
 3. Native kernels on the path: on databases whose segment spaces fit the
-   Pallas segment-sum kernels, all four strategies run with the Pallas
-   Möbius kernel, against the brute-force oracle (tiny database) and the
-   XLA ``segment_sum`` route (Hepatitis); every kernel call must have been
-   native, and lowering it must give a ``tpu_custom_call``.
+   Pallas segment-sum kernels, all four strategies run on them, against
+   the brute-force oracle (tiny database) and the XLA ``segment_sum``
+   route (Hepatitis); every kernel call must have been native, and
+   lowering it must give a ``tpu_custom_call``.  (The Möbius join runs on
+   the host in float64, so no device kernel serves it.)
 
 ``--chips 4`` runs only the device check and ``ShardedSparseExecutor`` over a
 four-chip ``data`` mesh on VisualGenome, against ``SparseExecutor`` on one
@@ -217,7 +218,7 @@ class KernelCalls:
     :mod:`repro.kernels.ops` while active: argument shapes and the
     ``interpret`` flag each call resolved to."""
 
-    NAMES = ("_mobius", "_edge_segment_sum", "_ones_segment_sum")
+    NAMES = ("_edge_segment_sum", "_ones_segment_sum")
 
     def __init__(self):
         self.calls = {name: [] for name in self.NAMES}
@@ -268,14 +269,14 @@ def family_keeps(point, schema):
     return [tuple(attrs[i:i + 2]) + rinds for i in range(0, len(attrs), 2)]
 
 
-def strategy_tables(db, lattice, keeps, names, **kw):
+def strategy_tables(db, lattice, keeps, names):
     """Every (point, keep) family table of the named strategies on the
     sparse executor, fetched per point as a search round fetches them."""
     import numpy as np
     from repro.core import make_strategy
     out = {}
     for name in names:
-        st = make_strategy(name, executor="sparse", **kw)
+        st = make_strategy(name, executor="sparse")
         st.prepare(db, lattice)
         for point in lattice:
             tabs = st.family_ct_many(point, keeps[point])
@@ -317,8 +318,7 @@ def native_kernel_phase(device, compiles: CompileCounter) -> None:
     keeps = {p: [p.all_ct_vars(tiny.schema, include_rind=True)]
              + family_keeps(p, tiny.schema) for p in lattice}
     with kernels.recording():
-        got = strategy_tables(tiny, lattice, keeps, names,
-                              use_pallas_mobius=True)
+        got = strategy_tables(tiny, lattice, keeps, names)
     for (name, point, keep), counts in got.items():
         assert_counts_equal(counts, oracle_ct(tiny, point, keep),
                             point.length,
@@ -326,17 +326,15 @@ def native_kernel_phase(device, compiles: CompileCounter) -> None:
     log(f"tiny db: {len(got)} family tables of {len(names)} strategies "
         f"with the Pallas kernels equal the oracle")
 
-    # the reference: each strategy on jax.ops.segment_sum and the jnp
-    # butterfly (strategies sum large cells in different orders, so each
-    # is compared with itself).  Chain length 1: PRECOUNT's complete table
+    # the reference: each strategy on jax.ops.segment_sum (strategies sum
+    # large cells in different orders, so each is compared with itself).  Chain length 1: PRECOUNT's complete table
     # of a two-relationship Hepatitis point has 26 M cells over 13 small
     # axes, and the TPU's tiled layout of its minor axes needs 2.5 GB for it
     hep = paper_benchmark_db("Hepatitis", seed=0, scale=1.0)
     lattice = build_lattice(hep.schema, 1)
     keeps = {p: family_keeps(p, hep.schema)[:2] for p in lattice}
     with kernels.recording():
-        got = strategy_tables(hep, lattice, keeps, names,
-                              use_pallas_mobius=True)
+        got = strategy_tables(hep, lattice, keeps, names)
     with xla_segment_sum_route():
         want = strategy_tables(hep, lattice, keeps, names)
     for (name, point, keep), counts in got.items():

@@ -21,8 +21,8 @@ from .distributed import (ShardedSparseExecutor, sharded_positive_ct,
 from .cache import CtCache
 from .engine import (CountingEngine, CachedFullPositives, DeltaReport,
                      OnDemandPositives, TupleIdPositives, key_deps)
-from .mobius import (butterfly_batch, complete_ct, complete_ct_many,
-                     positive_queries, superset_mobius)
+from .mobius import (complete_ct, complete_ct_many, positive_queries,
+                     superset_mobius)
 from .strategies import (Strategy, Precount, OnDemand, Hybrid, TupleId,
                          make_strategy, STRATEGIES)
 from .bdeu import bdeu_score_2d, bdeu_score_batch, family_score
@@ -41,7 +41,7 @@ __all__ = [
     "sharded_positive_ct", "sharded_sparse_positive_ct",
     "CtCache", "CountingEngine", "DeltaReport", "key_deps",
     "CachedFullPositives", "OnDemandPositives", "TupleIdPositives",
-    "butterfly_batch", "complete_ct", "complete_ct_many",
+    "complete_ct", "complete_ct_many",
     "positive_queries", "superset_mobius",
     "Strategy", "Precount", "OnDemand", "Hybrid", "TupleId",
     "make_strategy", "STRATEGIES",

@@ -12,6 +12,17 @@ unit of account in the byte-budgeted :class:`~repro.core.cache.CtCache`.
 ``nnz_rows`` reports the sparse-equivalent row count so benchmarks can be
 compared against the paper's Table 5 numbers.  Reads that block on the
 device go through :func:`to_host`, which spans them as ``host.read``.
+
+Counts are integers.  The executors contract in their dtype on the
+device (float32 holds every integer up to 2**24).  The small tables the
+Möbius join consumes are brought to the host in float64 (:func:`on_host`,
+exact to 2**53), where a table of NumPy counts keeps its algebra.  A
+projection of a pre-counted full table (HYBRID, PRECOUNT) is summed there
+and stays exact past 2**24: a VisualGenome chain of two relationships
+has ~18 M groundings in one cell.  A positive that sums its dropped axes
+on the device (ONDEMAND, TUPLEID, a service over a bare engine) carries
+the dtype's rounding past 2**24, and so does every complete cell the
+join subtracts (:mod:`repro.core.mobius`).
 """
 
 from __future__ import annotations
@@ -24,6 +35,12 @@ import numpy as np
 
 from ..obs.trace import NULL_TRACER, NullTracer
 from .variables import CtVar
+
+
+def _xp(counts):
+    """The array module of ``counts``: NumPy for host tables, jax.numpy
+    for device ones."""
+    return np if isinstance(counts, np.ndarray) else jnp
 
 
 def to_host(value, tracer: NullTracer = NULL_TRACER,
@@ -42,12 +59,29 @@ def to_host(value, tracer: NullTracer = NULL_TRACER,
     return out
 
 
+def on_host(tab: "CtTable", tracer: NullTracer = NULL_TRACER) -> "CtTable":
+    """``tab`` with its counts on the host in float64 (a device table is
+    read through :func:`to_host`; a float64 host table is returned as
+    is).
+
+    Usage::
+
+        exact = on_host(policy.positive(point, keep), engine.tracer)
+    """
+    if isinstance(tab.counts, np.ndarray) and tab.counts.dtype == np.float64:
+        return tab
+    return CtTable(tab.vars, to_host(tab.counts, tracer,
+                                     "on_host").astype(np.float64))
+
+
 @dataclass
 class CtTable:
     vars: Tuple[CtVar, ...]
     counts: jnp.ndarray               # shape == tuple(v.card for v in vars)
 
     def __post_init__(self) -> None:
+        if isinstance(self.counts, np.generic):    # NumPy's 0-d results
+            self.counts = np.asarray(self.counts)
         expect = tuple(v.card for v in self.vars)
         if tuple(self.counts.shape) != expect:
             raise ValueError(f"ct shape {self.counts.shape} != vars {expect}")
@@ -64,10 +98,14 @@ class CtTable:
 
     def nnz_rows(self, tracer: NullTracer = NULL_TRACER) -> int:
         """Sparse-equivalent number of ct-table rows (paper Table 5)."""
+        if isinstance(self.counts, np.ndarray):
+            return int(np.count_nonzero(self.counts))
         return int(to_host(jnp.count_nonzero(self.counts), tracer,
                            "nnz_rows"))
 
     def total(self, tracer: NullTracer = NULL_TRACER) -> float:
+        if isinstance(self.counts, np.ndarray):
+            return float(np.sum(self.counts))
         return float(to_host(jnp.sum(self.counts), tracer, "total"))
 
     # -- algebra ------------------------------------------------------------
@@ -81,12 +119,13 @@ class CtTable:
         missing = [v for v in keep if v not in self.vars]
         if missing:
             raise KeyError(f"project: vars not in table: {missing}")
+        xp = _xp(self.counts)
         drop = tuple(i for i, v in enumerate(self.vars) if v not in keep)
-        counts = jnp.sum(self.counts, axis=drop) if drop else self.counts
+        counts = xp.sum(self.counts, axis=drop) if drop else self.counts
         cur = tuple(v for v in self.vars if v in keep)
         # permute to requested order
         perm = tuple(cur.index(v) for v in keep)
-        counts = jnp.transpose(counts, perm) if perm != tuple(range(len(perm))) else counts
+        counts = xp.transpose(counts, perm) if perm != tuple(range(len(perm))) else counts
         return CtTable(keep, counts)
 
     def transpose_to(self, order: Sequence[CtVar]) -> "CtTable":
@@ -94,11 +133,18 @@ class CtTable:
         if set(order) != set(self.vars):
             raise ValueError("transpose_to needs the same var set")
         perm = tuple(self.vars.index(v) for v in order)
-        return CtTable(order, jnp.transpose(self.counts, perm))
+        return CtTable(order, _xp(self.counts).transpose(self.counts, perm))
+
+    def _same_side(self, other: "CtTable") -> "CtTable":
+        """``other`` where ``self`` lives: a host table pulls a device
+        operand to the host, so host counts stay exact."""
+        return on_host(other) if isinstance(self.counts, np.ndarray) \
+            else other
 
     def outer(self, other: "CtTable") -> "CtTable":
         """Tensor (Cartesian) product — used to extend a component ct over
         unconstrained variables."""
+        other = self._same_side(other)
         a = self.counts.reshape(self.counts.shape + (1,) * other.counts.ndim)
         return CtTable(self.vars + other.vars, a * other.counts)
 
@@ -106,11 +152,11 @@ class CtTable:
         return CtTable(self.vars, self.counts * c)
 
     def __sub__(self, other: "CtTable") -> "CtTable":
-        other = other.transpose_to(self.vars)
+        other = self._same_side(other).transpose_to(self.vars)
         return CtTable(self.vars, self.counts - other.counts)
 
     def __add__(self, other: "CtTable") -> "CtTable":
-        other = other.transpose_to(self.vars)
+        other = self._same_side(other).transpose_to(self.vars)
         return CtTable(self.vars, self.counts + other.counts)
 
 

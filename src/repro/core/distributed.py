@@ -4,9 +4,8 @@ Counting is linear in edge rows, so the JOIN sweep data-parallelises
 perfectly: shard every relationship's edge list over the ``data`` mesh axis,
 run the gather -> one-hot multiply -> segment-sum hop on local rows, and
 ``psum`` the per-entity partials.  Entity-indexed messages stay replicated
-(they are small: n_entities x value-space); the ct value space itself can be
-sharded over ``model`` for the Möbius/projection phase, which is elementwise
-across the attribute axes.
+(they are small: n_entities x value-space); the Möbius join runs on the
+host over the small tables the contraction returns.
 
 Two mesh-sharded paths live here, mirroring the two executors:
 
@@ -45,7 +44,6 @@ from .contract import CostStats, entity_onehot, _onehot, _expand
 from .ct import CtTable
 from .database import RelationalDB
 from .executors import EXECUTORS, SparseExecutor, _kr_segment_sum
-from .mobius import superset_mobius
 from .variables import Atom, CtVar, LatticePoint, Var, edge_var
 
 
@@ -249,8 +247,7 @@ class ShardedSparseExecutor(SparseExecutor):
     contraction.
 
     Args:
-        dtype / mobius_fn / use_pallas_mobius: as for
-            :class:`~repro.core.executors.Executor`.
+        dtype: as for :class:`~repro.core.executors.Executor`.
         mesh: the device mesh; defaults to a 1-D mesh over every visible
             device named ``(axis,)``.
         axis: mesh axis name to shard edge/entity rows over.
@@ -266,11 +263,9 @@ class ShardedSparseExecutor(SparseExecutor):
 
     name = "sparse_sharded"
 
-    def __init__(self, dtype=jnp.float32, mobius_fn=None,
-                 use_pallas_mobius: bool = False,
+    def __init__(self, dtype=jnp.float32,
                  mesh: Optional[Mesh] = None, axis: str = "data"):
-        super().__init__(dtype=dtype, mobius_fn=mobius_fn,
-                         use_pallas_mobius=use_pallas_mobius)
+        super().__init__(dtype=dtype)
         if mesh is None:
             mesh = Mesh(np.asarray(jax.devices()), (axis,))
         if axis not in mesh.axis_names:
@@ -524,32 +519,3 @@ def merge_stacked(stacked: jnp.ndarray, axis_name: str = "data"
             check_vma=False)
         return red(stacked)
     return jnp.sum(stacked, axis=0)
-
-
-def superset_mobius_sharded(stack: jnp.ndarray, k: int, *, mesh: Mesh,
-                            axis: str = "model") -> jnp.ndarray:
-    """Möbius butterfly with the flattened attribute axis sharded over
-    ``axis``: the transform is elementwise across attributes, so each rank
-    transforms its own columns under ``shard_map`` and no communication is
-    needed.
-
-    Args:
-        stack: the butterfly input; the leading ``k`` axes are the binary
-            indicator axes, the rest is the attribute value space, whose
-            flattened size must divide evenly over ``axis``.
-        k: number of leading indicator axes to transform over.
-        mesh / axis: device mesh and the axis to shard attributes over.
-
-    Returns:
-        The transformed stack, same shape as ``stack``.
-
-    Usage::
-
-        neg = superset_mobius_sharded(stack, k, mesh=mesh, axis="model")
-    """
-    lead = stack.shape[:k]
-    d = int(np.prod(stack.shape[k:])) if stack.ndim > k else 1
-    spec = P(*([None] * k + [axis]))
-    fn = shard_map(lambda x: superset_mobius(x, k), mesh=mesh,
-                   in_specs=spec, out_specs=spec, check_vma=False)
-    return jax.jit(fn)(stack.reshape(lead + (d,))).reshape(stack.shape)

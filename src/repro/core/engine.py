@@ -45,9 +45,9 @@ surviving same-executor entries run through ONE
 delta view.  Derived ``"fam"``/``"complete"`` tables are ALSO updated in
 place: the Möbius transform is linear, so the positive block deltas push
 through the butterfly (:func:`~repro.core.mobius.complete_ct_delta_many`,
-one fused dispatch per ``(shape, perm)`` group) and add onto the resident
-tables exactly.  Above the cost threshold the entry is dropped instead and
-recomputed on next miss (post-counting the write).  Entries whose
+on the host) and add onto the resident tables.  Above
+the cost threshold the entry is dropped instead and recomputed on next
+miss (post-counting the write).  Entries whose
 dependency tags miss the delta — including every ``"hist"`` on a fact
 delta — are retained untouched.  Attribute deltas invalidate exactly the
 entries whose tags intersect the written ``(etype, attr)`` columns
@@ -65,7 +65,7 @@ import jax.numpy as jnp
 
 from .cache import CtCache
 from .contract import CostStats
-from .ct import CtTable
+from .ct import CtTable, on_host
 from .database import AttrDelta, FactDelta, RelationalDB
 from .executors import Executor, make_executor, project_columns
 from .mobius import complete_ct_delta_many
@@ -167,7 +167,7 @@ class CountingEngine:
         # version it was computed under
         self.cache.deps_fn = key_deps
         self.cache.version_fn = lambda: self.db.version
-        self.dtype = dtype
+        self.dtype = self.executor.dtype     # the join subtracts in it too
         # one rows-counted set per engine: policies AND the counting
         # service share artefact key namespaces ("pos"/"full"/...), so
         # Table 5's "once per distinct artefact" accounting must be shared
@@ -199,39 +199,21 @@ class CountingEngine:
         inside a ``count.positive`` span of one table."""
         tr = self.tracer
         with tr.span("count.positive") as sp:
+            plan = self.plan(point, keep)
             if tr.enabled:
-                sp.set(tables=1)
-            return self.executor.positive(self.db, self.plan(point, keep),
-                                          self.stats)
+                sp.set(tables=1, hops=plan.hops)
+            return self.executor.positive(self.db, plan, self.stats)
 
     def hist(self, var: Var, keep: Tuple[CtVar, ...]) -> CtTable:
+        """A variable's histogram over ``keep``, cached on the host in
+        float64: the Möbius join multiplies histograms into its blocks
+        there."""
         key = ("hist", self.executor.name, var, tuple(keep))
         hit = self.cache.get(key)
         if hit is None:
-            hit = self.cache.put(key, self.executor.hist(
-                self.db, var, tuple(keep), self.stats))
+            hit = self.cache.put(key, on_host(self.executor.hist(
+                self.db, var, tuple(keep), self.stats), self.tracer))
         return hit
-
-    def mobius_fn(self):
-        """The executor's negative-phase step, ``(stack, k) -> stack``."""
-        return self.executor.mobius
-
-    def mobius_batch_fn(self):
-        """The executor's BATCHED negative-phase step,
-        ``(stacks, k) -> [stack]`` — one jitted transform over many
-        same-shape butterfly stacks (see :meth:`~repro.core.executors
-        .Executor.mobius_batch`).  This is what lets a serving layer or a
-        search round pay one negative-phase dispatch per stack *shape*
-        rather than one per family."""
-        return self.executor.mobius_batch
-
-    def mobius_fused_fn(self):
-        """The executor's FUSED batched negative phase,
-        ``(block_lists, k, perm) -> [table array]`` — butterfly-stack
-        assembly, transform and final transpose in one jitted dispatch
-        per ``(shape, perm)`` group (see :meth:`~repro.core.executors
-        .Executor.mobius_batch_fused`)."""
-        return self.executor.mobius_batch_fused
 
     # -- delta count maintenance --------------------------------------------
     def apply_delta(self, delta,
@@ -261,8 +243,7 @@ class CountingEngine:
         * derived ``"fam"``/``"complete"`` table and the delta is small →
           **updated in place through the butterfly**: the Möbius transform
           is linear, so the block deltas (delta-view contractions) push
-          through :func:`~repro.core.mobius.complete_ct_delta_many` — one
-          fused negative-phase dispatch per ``(shape, perm)`` group — and
+          through :func:`~repro.core.mobius.complete_ct_delta_many` and
           add onto the resident tables, bit-exact vs recompute.  Entries
           whose kept indicators sum ``delta.rel`` out are provably
           unaffected and retained;
@@ -369,17 +350,13 @@ class CountingEngine:
                 elif cache.discard(key):
                     report.invalidated += 1
 
-            # (a) derived tables: push the block deltas through the fused
-            # butterfly — one negative-phase dispatch per (shape, perm)
-            # group — and add onto the resident tables
+            # (a) derived tables: push the block deltas through the
+            # butterfly and add onto the resident tables
             if fam_items:
                 provider = _DeltaPositives(self, delta_db)
                 outs = complete_ct_delta_many(
                     [(point, keep) for _, point, keep in fam_items], rel,
-                    provider, self.stats,
-                    mobius_fn=self.mobius_fn(),
-                    mobius_batch_fn=self.mobius_batch_fn(),
-                    mobius_fused_fn=self.mobius_fused_fn())
+                    provider, self.stats, self.dtype)
                 for (key, _, _), (status, dtab) in zip(fam_items, outs):
                     if status == "zero":
                         report.retained += 1
@@ -594,7 +571,12 @@ class OnDemandPositives(_Policy):
 class CachedFullPositives(_Policy):
     """Serve positives by *projection* from full-attribute positive tables
     contracted once per lattice point — zero data access afterwards
-    (HYBRID / PRECOUNT).  Evicted entries are re-contracted on miss."""
+    (HYBRID / PRECOUNT).  Evicted entries are re-contracted on miss.
+
+    A full table is read to the host once, as it is contracted, and
+    cached there in float64 (its bytes count against the cache budget):
+    its cells fit the executor's dtype, while a projection sums them past
+    float32's 2**24, and stays exact on the host."""
 
     supports_batch_prefetch = True
 
@@ -613,7 +595,7 @@ class CachedFullPositives(_Policy):
         hit = eng.cache.get(key)
         if hit is None:
             with eng.stats.timer("positive"):
-                hit = eng.contract(point, None)
+                hit = on_host(eng.contract(point, None), eng.tracer)
             self._count_rows_once(key, hit)
             eng.cache.put(key, hit)
         return hit
@@ -631,6 +613,7 @@ class CachedFullPositives(_Policy):
 
     def absorb(self, point, keep, tab):
         key = self._full_key(point)
+        tab = on_host(tab, self.engine.tracer)
         self._count_rows_once(key, tab)
         self.engine.cache.put(key, tab)
 

@@ -19,11 +19,10 @@ the message representation:
   VisualGenome-scale configuration reachable.
 
 Both executors expose the same interface (``positive`` / ``hist`` /
-``leaf_hop`` / ``root_reduce`` / ``mobius``) so strategies, the Möbius join
-and the tuple-ID variant are executor-agnostic.  The negative-phase step
-(``mobius``) defaults to the pure-jnp superset transform and can be wired
-to the Pallas kernel (``kernels/mobius_kernel.py``) with
-``use_pallas_mobius=True``.
+``leaf_hop`` / ``root_reduce``) so strategies, the Möbius join and the
+tuple-ID variant are executor-agnostic.  The negative phase is not an
+executor's: the Möbius join runs on the host, subtracting in the
+executor's dtype (:mod:`repro.core.mobius`).
 """
 
 from __future__ import annotations
@@ -96,13 +95,8 @@ class Executor:
 
     name = "base"
 
-    def __init__(self, dtype=jnp.float32, mobius_fn=None,
-                 use_pallas_mobius: bool = False):
+    def __init__(self, dtype=jnp.float32):
         self.dtype = dtype
-        if mobius_fn is None and use_pallas_mobius:
-            from ..kernels.ops import mobius_nd
-            mobius_fn = mobius_nd
-        self._mobius_fn = mobius_fn
         # (stack key, padded batch) -> (db, jitted vmapped evaluator)
         self._batch_cache: dict = {}
         # request tracer for jit-dispatch spans (NULL_TRACER is free); a
@@ -117,135 +111,6 @@ class Executor:
             if tr.enabled:
                 sp.set(nbytes=int(host.nbytes))
             return jnp.asarray(host)
-
-    # -- negative phase -----------------------------------------------------
-    def mobius(self, stack: jnp.ndarray, k: int) -> jnp.ndarray:
-        """Superset Möbius transform over the leading ``k`` binary axes —
-        the Möbius join's butterfly step."""
-        if self._mobius_fn is not None:
-            return self._mobius_fn(stack, k)
-        from .mobius import superset_mobius
-        return superset_mobius(stack, k)
-
-    def mobius_batch(self, stacks: Sequence[jnp.ndarray],
-                     k: int) -> List[jnp.ndarray]:
-        """Batched negative phase: one jitted transform over MANY same-shape
-        butterfly stacks.
-
-        The stacks are stacked along a new batch axis which is then moved
-        to the trailing (attribute) side, so the single-stack step
-        (:meth:`mobius` — the Pallas kernel under ``use_pallas_mobius``,
-        the pure-jnp mirror otherwise) runs once over the widened
-        attribute space; one dispatch replaces ``len(stacks)``.  The batch
-        axis is padded to the next power of two (padding replays the first
-        stack) so the jit cache is keyed by a handful of sizes, and the
-        traced evaluator is kept in ``_batch_cache`` like the stacked
-        positive path.  Results are bit-identical to per-stack
-        :meth:`mobius` (the transform is elementwise across the batch
-        axis).
-
-        Args:
-            stacks: same-shape arrays, each ``(2,)*k + attr_shape``.
-            k: number of leading indicator axes.
-
-        Returns:
-            One transformed array per input, in input order.
-
-        Usage::
-
-            outs = executor.mobius_batch(stacks, k)
-        """
-        stacks = list(stacks)
-        if not stacks:
-            return []
-        if len(stacks) == 1:
-            return [self.mobius(stacks[0], k)]
-        shape = tuple(stacks[0].shape)
-        b = len(stacks)
-        b_pad = 1 << max(b - 1, 0).bit_length()
-        key = ("mobius_batch", shape, k, b_pad)
-        fn = self._batch_cache.get(key)
-        if fn is None:
-            from .mobius import trailing_batch_transform
-
-            def run(batch):
-                return trailing_batch_transform(batch, k, self.mobius)
-
-            fn = self._batch_cache[key] = jax.jit(run)
-        batch = jnp.stack(stacks + [stacks[0]] * (b_pad - b))
-        with self.tracer.span("exec.mobius_batch", stacks=b, k=k,
-                              b_pad=b_pad):
-            out = fn(batch)
-        return [out[i] for i in range(b)]
-
-    def mobius_batch_fused(self, block_lists: Sequence[Sequence[jnp.ndarray]],
-                           k: int, perm: Tuple[int, ...]
-                           ) -> List[jnp.ndarray]:
-        """FULLY fused batched negative phase: butterfly-stack assembly,
-        superset transform and the finalise transpose for many same-shape
-        queries in ONE jitted dispatch per ``(shape, perm)`` group.
-
-        :meth:`mobius_batch` still paid per-query eager glue — a
-        ``jnp.stack`` + reshape to assemble each query's butterfly stack
-        and a ``jnp.transpose`` to the request layout afterwards.  Here
-        the raw aligned blocks go straight into the jitted evaluator: it
-        stacks ALL queries' blocks, runs the transform with the batch
-        axis moved to the trailing (elementwise) side, applies the shared
-        final transpose, and returns one array per query — per-query
-        results are sliced *inside* the jit, so the whole group is a
-        single dispatch.  Padding (batch axis to the next power of two,
-        replaying the first query) keeps the jit cache keyed by a handful
-        of sizes.  Results are bit-identical to the unfused path (the
-        transform is elementwise across the batch axis; no op reordering
-        occurs).
-
-        Args:
-            block_lists: one sequence of ``2**k`` aligned blocks per
-                query, each of the same attr shape, in the
-                ``itertools.product((0, 1), repeat=k)`` order the
-                butterfly stack is built in.
-            k: number of leading indicator axes.
-            perm: the finalise transpose from transform layout
-                (``(2,)*k`` + attr axes) to request layout — shared by
-                the whole group.
-
-        Returns:
-            One complete-table array per query (request layout), in input
-            order.
-
-        Usage::
-
-            outs = executor.mobius_batch_fused(blocks, k, bp.perm)
-        """
-        block_lists = [list(bs) for bs in block_lists]
-        if not block_lists:
-            return []
-        attr_shape = tuple(block_lists[0][0].shape)
-        b = len(block_lists)
-        b_pad = 1 << max(b - 1, 0).bit_length()
-        perm = tuple(perm)
-        key = ("mobius_fused", attr_shape, k, perm, b_pad)
-        fn = self._batch_cache.get(key)
-        if fn is None:
-            tperm = (0,) + tuple(p + 1 for p in perm)
-
-            def run(*blks):
-                x = jnp.stack(blks).reshape(
-                    (b_pad,) + (2,) * k + attr_shape)
-                moved = jnp.moveaxis(x, 0, -1)           # batch -> trailing
-                y = jnp.moveaxis(self.mobius(moved, k), -1, 0)
-                if tperm != tuple(range(len(tperm))):
-                    y = jnp.transpose(y, tperm)
-                return tuple(y[i] for i in range(b_pad))
-
-            fn = self._batch_cache[key] = jax.jit(run)
-        flat = [blk for bs in block_lists for blk in bs]
-        for bs in [block_lists[0]] * (b_pad - b):        # pad: replay query 0
-            flat.extend(bs)
-        with self.tracer.span("exec.mobius_batch_fused", stacks=b, k=k,
-                              b_pad=b_pad):
-            outs = fn(*flat)
-        return list(outs[:b])
 
     def local_mode(self):
         """Context for tiny side computations — the engine's delta count

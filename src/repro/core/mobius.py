@@ -13,26 +13,31 @@ planner/executor/cache machinery — with disconnected sub-patterns
 factorising into outer products of component tables and per-variable
 histograms.
 
+The join runs on the host (:func:`~repro.core.ct.on_host`).  Its tables
+are small — a family's cells, 2^k blocks of them — while its counts are
+not: a complete cell counts groundings of every variable of the point
+(200 k^3 for a VisualGenome chain of two relationships), and a positive
+cell of such a chain ~18 M, past the 2**24 up to which float32 holds
+integers.  The blocks arrive in float64, exact where the provider
+projects a pre-counted table (HYBRID, PRECOUNT); the inclusion–exclusion
+subtracts in ``dtype``, the precision the engine contracts in, so a
+complete cell carries that precision's rounding.  The all-true cells,
+which no subtraction writes, are the positive counts as the provider
+gave them.
+
 Two equivalent evaluation orders are implemented:
 
 * ``blockwise`` — explicit 3^k-term sum, handles kept edge attributes (whose
   axes only exist while their relation is true; when false they collapse to
   the N/A slot).
 * ``butterfly`` — the superset Möbius transform as k in-place passes
-  ``F-slice = *-slice − T-slice`` over a [2^k, D] stack; this is the
-  memory-bound transform the Pallas kernel (kernels/mobius_kernel.py)
-  implements.  Used when no edge-attr axes are kept.  The ``mobius_fn``
-  hook is normally the executor's negative-phase step
-  (:meth:`repro.core.executors.Executor.mobius`), which dispatches to the
-  Pallas kernel when the executor was built with ``use_pallas_mobius``.
+  ``F-slice = *-slice − T-slice`` over a [2^k, D] stack
+  (:func:`superset_mobius`).  Used when no edge-attr axes are kept.
 
-The butterfly path also batches ACROSS queries: butterfly input stacks of
-same-``tree_signature`` families are same-shape by construction, so
-:func:`complete_ct_many` stacks them into one ``[B, 2^k, D]`` tensor and
-runs a single transform per shape group (:func:`butterfly_batch`, or the
-executor's jitted :meth:`~repro.core.executors.Executor.mobius_batch`) —
-one negative-phase dispatch for a whole hill-climbing round instead of one
-per family.
+:func:`complete_ct_many` serves many queries with one block memo: the
+families of one point share sub-pattern blocks (most notably the
+all-unconstrained block, a product of histograms), and each distinct block
+is projected and aligned once.
 
 The transform output is integral and non-negative (counts); property tests
 assert both.
@@ -41,15 +46,14 @@ assert both.
 from __future__ import annotations
 
 import itertools
-from typing import Callable, Dict, List, Optional, Protocol, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Protocol, Sequence, Set, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import NULL_TRACER, NullTracer
 from .contract import CostStats
-from .ct import CtTable, scalar_table
+from .ct import CtTable, on_host
 from .variables import (Atom, CtVar, LatticePoint, Var, connected_components,
                         rind_var)
 
@@ -63,71 +67,29 @@ class PositiveProvider(Protocol):
 
 
 # --------------------------------------------------------------------------
-# superset Möbius transform (pure-jnp reference; Pallas kernel mirrors this)
+# superset Möbius transform
 # --------------------------------------------------------------------------
 
-def superset_mobius(stack: jnp.ndarray, k: int) -> jnp.ndarray:
+def _in_dtype(op, a: np.ndarray, b: np.ndarray, dtype) -> np.ndarray:
+    """``op(a, b)`` computed in ``dtype`` and held in ``a``'s dtype."""
+    dt = jnp.dtype(dtype)
+    return op(a.astype(dt), b.astype(dt)).astype(a.dtype)
+
+
+def superset_mobius(stack: np.ndarray, k: int,
+                    dtype=jnp.float32) -> np.ndarray:
     """In the leading ``k`` axes (each of size 2, index 1 = "relation true",
     index 0 = "unconstrained"), replace index 0 with "relation false" by
     applying ``x0 <- x0 - x1`` per axis.  Equivalent to
-    ``N[A] = sum_{S >= A} (-1)^{|S|-|A|} Y[S]``."""
-    x = stack
+    ``N[A] = sum_{S >= A} (-1)^{|S|-|A|} Y[S]``.  The subtractions run in
+    ``dtype``; the all-true corner, which none writes, keeps ``stack``'s
+    values."""
+    x = np.asarray(stack)
     for i in range(k):
-        x0 = jnp.take(x, 0, axis=i) - jnp.take(x, 1, axis=i)
-        x1 = jnp.take(x, 1, axis=i)
-        x = jnp.stack([x0, x1], axis=i)
+        x1 = np.take(x, 1, axis=i)
+        x0 = _in_dtype(np.subtract, np.take(x, 0, axis=i), x1, dtype)
+        x = np.stack([x0, x1], axis=i)
     return x
-
-
-def butterfly_batch(stacks: Sequence[jnp.ndarray], k: int,
-                    mobius_fn: Optional[Callable[[jnp.ndarray, int],
-                                                 jnp.ndarray]] = None
-                    ) -> List[jnp.ndarray]:
-    """Apply the superset Möbius transform to MANY same-shape butterfly
-    stacks in one dispatch.
-
-    The transform only acts on the leading ``k`` binary axes and is
-    elementwise over everything else, so batching is a layout trick: the
-    stacks are stacked into ``[B, 2, ..., 2, attrs]``, the batch axis is
-    moved to the *trailing* (attribute) side, and ``mobius_fn`` — any
-    single-stack transform, the pure-jnp :func:`superset_mobius` or the
-    Pallas kernel adapter — runs once over the widened attribute space.
-    Results are bit-identical to per-stack application (the transform is
-    elementwise across the batch axis; no op reordering occurs).
-
-    Args:
-        stacks: same-shape arrays, each ``(2,)*k + attr_shape``.
-        k: number of leading indicator axes.
-        mobius_fn: single-stack transform ``(stack, k) -> stack``; defaults
-            to :func:`superset_mobius`.
-
-    Returns:
-        One transformed array per input, in input order.
-
-    Usage::
-
-        outs = butterfly_batch([s1, s2, s3], k)
-    """
-    stacks = list(stacks)
-    if not stacks:
-        return []
-    fn = mobius_fn if mobius_fn is not None else superset_mobius
-    if len(stacks) == 1:
-        return [fn(stacks[0], k)]
-    out = trailing_batch_transform(jnp.stack(stacks), k, fn)
-    return [out[i] for i in range(len(stacks))]
-
-
-def trailing_batch_transform(batch: jnp.ndarray, k: int,
-                             fn: Callable[[jnp.ndarray, int], jnp.ndarray]
-                             ) -> jnp.ndarray:
-    """The batching layout trick shared by :func:`butterfly_batch` and
-    :meth:`~repro.core.executors.Executor.mobius_batch`: move the leading
-    batch axis of ``[B, 2..2, attrs]`` to the trailing (attribute) side —
-    where the transform is elementwise — apply the single-stack ``fn``
-    once, and move it back."""
-    moved = jnp.moveaxis(batch, 0, -1)              # [2..2, attrs, B]
-    return jnp.moveaxis(fn(moved, k), -1, 0)
 
 
 # --------------------------------------------------------------------------
@@ -168,9 +130,16 @@ def _butterfly_plan(point: LatticePoint,
     return _ButterflyPlan(keep, kept_attrs, effective, k, perm)
 
 
+def _join_blocks(keep: Tuple[CtVar, ...]) -> int:
+    """The blocks one complete table's Möbius join assembles: 2^k, one per
+    truth assignment of the k relationships with a kept indicator or edge
+    axis (the ``blocks`` counter of ``count.negative`` spans)."""
+    return 1 << len({v.owner[0] for v in keep if v.kind in ("rind", "edge")})
+
+
 def _butterfly_blocks(point: LatticePoint, bp: _ButterflyPlan,
                       provider: PositiveProvider,
-                      memo: Optional[Dict] = None) -> List[jnp.ndarray]:
+                      memo: Optional[Dict] = None) -> List[np.ndarray]:
     """The aligned transform-input blocks, one per ``{*,T}^k`` corner in
     ``itertools.product`` order: Y[c] = ct_+(T-set of c) over the kept
     attrs (positive phase of the Möbius join).
@@ -201,24 +170,15 @@ def _butterfly_blocks(point: LatticePoint, bp: _ButterflyPlan,
     return blocks
 
 
-def _butterfly_stack(point: LatticePoint, bp: _ButterflyPlan,
-                     provider: PositiveProvider,
-                     memo: Optional[Dict] = None) -> jnp.ndarray:
-    """The transform input: the blocks of :func:`_butterfly_blocks`
-    stacked to ``(2,)*k + attr_shape`` (eager assembly glue; the fused
-    batched path skips this and hands the raw blocks to the jitted
-    evaluator instead — see :meth:`~repro.core.executors.Executor
-    .mobius_batch_fused`)."""
-    blocks = _butterfly_blocks(point, bp, provider, memo)
+def _butterfly_transform(bp: _ButterflyPlan,
+                         blocks: Sequence[np.ndarray], dtype) -> CtTable:
+    """Stack the blocks to ``(2,)*k + attr_shape``, transform in
+    ``dtype``, and transpose to the request's axis order: the complete
+    ct-table."""
     attr_shape = tuple(v.card for v in bp.kept_attrs)
-    return jnp.stack(blocks).reshape((2,) * bp.k + attr_shape)
-
-
-def _butterfly_finalise(bp: _ButterflyPlan, out: jnp.ndarray) -> CtTable:
-    """Transform output -> the complete ct-table in request axis order."""
-    final = jnp.transpose(out, bp.perm) \
-        if bp.perm != tuple(range(len(bp.perm))) else out
-    return CtTable(bp.keep, final)
+    stack = np.stack(blocks).reshape((2,) * bp.k + attr_shape)
+    return CtTable(bp.keep, np.transpose(
+        superset_mobius(stack, bp.k, dtype), bp.perm))
 
 
 # --------------------------------------------------------------------------
@@ -229,7 +189,8 @@ def _pattern_table(point: LatticePoint, rels: Set[str],
                    keep_axes: Tuple[CtVar, ...],
                    provider: PositiveProvider) -> CtTable:
     """ct_+ of the sub-pattern with ``rels`` true, over all vars of ``point``,
-    projected onto ``keep_axes`` (entity attrs + edge attrs of rels)."""
+    projected onto ``keep_axes`` (entity attrs + edge attrs of rels), on
+    the host in float64: products of exact counts, exact to 2**53."""
     atoms = tuple(a for a in point.atoms if a.rel in rels)
     out: Optional[CtTable] = None
     covered: Set[Var] = set()
@@ -239,7 +200,7 @@ def _pattern_table(point: LatticePoint, rels: Set[str],
         ckeep = tuple(v for v in keep_axes
                       if (v.kind == "attr" and v.owner[0] in cp.vars)
                       or (v.kind == "edge" and v.owner[0] in comp_rels))
-        t = provider.positive(cp, ckeep)
+        t = on_host(provider.positive(cp, ckeep))
         out = t if out is None else out.outer(t)
         covered.update(cp.vars)
     for var in point.vars:
@@ -247,7 +208,7 @@ def _pattern_table(point: LatticePoint, rels: Set[str],
             continue
         vkeep = tuple(v for v in keep_axes
                       if v.kind == "attr" and v.owner[0] == var)
-        h = provider.hist(var, vkeep)
+        h = on_host(provider.hist(var, vkeep))
         out = h if out is None else out.outer(h)
     assert out is not None
     return out.transpose_to(tuple(v for v in keep_axes if v in out.vars)) \
@@ -315,10 +276,12 @@ def complete_ct(point: LatticePoint, keep: Sequence[CtVar],
                 provider: PositiveProvider,
                 stats: Optional[CostStats] = None,
                 use_butterfly: bool = True,
-                mobius_fn: Optional[Callable[[jnp.ndarray, int], jnp.ndarray]] = None,
-                tracer: NullTracer = NULL_TRACER) -> CtTable:
-    """Complete ct-table over ``keep`` — the Möbius Join, inside a
-    ``count.negative`` span of one table on ``tracer``.
+                tracer: NullTracer = NULL_TRACER,
+                dtype=jnp.float32) -> CtTable:
+    """Complete ct-table over ``keep`` — the Möbius Join, on the host,
+    subtracting in ``dtype`` (the engine's), inside a ``count.negative``
+    span of one table on ``tracer`` (``blocks`` and ``blocks_built`` both
+    its 2^k blocks: one table shares none).
 
     ``keep`` may contain entity-attr axes, edge-attr axes, and relationship
     indicator axes of the point.  Relations with neither a kept indicator nor
@@ -328,93 +291,78 @@ def complete_ct(point: LatticePoint, keep: Sequence[CtVar],
     """
     with tracer.span("count.negative") as sp:
         if tracer.enabled:
-            sp.set(tables=1)
+            n = _join_blocks(tuple(keep))
+            sp.set(tables=1, blocks=n, blocks_built=n)
         return _complete_ct(point, keep, provider, stats, use_butterfly,
-                            mobius_fn)
+                            dtype)
 
 
 def _complete_ct(point: LatticePoint, keep: Sequence[CtVar],
                  provider: PositiveProvider,
                  stats: Optional[CostStats], use_butterfly: bool,
-                 mobius_fn: Optional[Callable[[jnp.ndarray, int],
-                                              jnp.ndarray]]) -> CtTable:
+                 dtype, memo: Optional[Dict] = None) -> CtTable:
     keep = tuple(keep)
+    bp = _butterfly_plan(point, keep) if use_butterfly else None
+    if bp is not None:
+        # stack Y[c in {*,T}^k] = ct_+(T-set of c), butterfly to {F,T}^k;
+        # with no edge axes the complete table IS the transform output, up
+        # to axis order.
+        tab = _butterfly_transform(
+            bp, _butterfly_blocks(point, bp, provider, memo), dtype)
+    else:
+        tab = CtTable(keep, _blockwise(point, keep, provider, dtype))
+    if stats is not None:
+        stats.ct_cells += tab.size
+    return tab
+
+
+def _blockwise(point: LatticePoint, keep: Tuple[CtVar, ...],
+               provider: PositiveProvider, dtype) -> np.ndarray:
+    """The complete table by the explicit inclusion–exclusion sum, block
+    by block (the order that handles kept edge-attr axes), summed in
+    ``dtype``."""
     kept_attrs = tuple(v for v in keep if v.kind == "attr")
     kept_edges: Dict[str, List[CtVar]] = {}
     for v in keep:
         if v.kind == "edge":
             kept_edges.setdefault(v.owner[0], []).append(v)
     kept_rinds = {v.owner[0] for v in keep if v.kind == "rind"}
-
     effective = sorted(set(kept_edges) | kept_rinds)
-    k = len(effective)
+    final = np.zeros(tuple(v.card for v in keep))
+    for r_bits in itertools.product((0, 1), repeat=len(effective)):
+        A = {r for r, b in zip(effective, r_bits) if b == 1}
+        B = [r for r in effective if r not in A]
+        axes_A = kept_attrs + tuple(
+            v for r in sorted(A) for v in kept_edges.get(r, ()))
+        acc: Optional[np.ndarray] = None
+        for j in range(len(B) + 1):
+            for S in itertools.combinations(B, j):
+                t = _pattern_table(point, A | set(S), axes_A, provider)
+                contrib = t.transpose_to(axes_A).counts
+                acc = contrib if acc is None else _in_dtype(
+                    np.subtract if j % 2 else np.add, acc, contrib, dtype)
+        assert acc is not None
+        _embed(final, keep, A, CtTable(axes_A, acc))
+    return final
 
-    # final tensor
-    shape = tuple(v.card for v in keep)
-    final = jnp.zeros(shape, dtype=jnp.result_type(jnp.float32))
 
-    kept_rinds_pre = {v.owner[0] for v in keep if v.kind == "rind"}
-    # blocks for distinct A are disjoint iff every rel with a kept edge axis
-    # also has its indicator kept (then the rind bits separate all blocks);
-    # a kept edge axis WITHOUT its rind spans the N/A slot that the A-less
-    # block writes, so those must accumulate.
-    disjoint_blocks = all(r in kept_rinds_pre for v in keep if v.kind == "edge"
-                          for r in [v.owner[0]])
-
-    def embed(A: Set[str], table: CtTable) -> None:
-        """Write block-A into `final`.  When blocks are disjoint a
-        dynamic_update_slice (one cheap primitive) replaces the generic
-        scatter-add that ``.at[idx].add`` lowers to (§Perf H3 it.2)."""
-        nonlocal final
-        starts: List[int] = []
-        block_axes: List[CtVar] = []
-        for v in keep:
-            if v.kind == "rind":
-                starts.append(1 if v.owner[0] in A else 0)
-            elif v.kind == "edge" and v.owner[0] not in A:
-                starts.append(v.card - 1)       # N/A slot
-            else:
-                starts.append(0)
-                block_axes.append(v)
-        aligned = table.transpose_to(tuple(block_axes))
-        block = aligned.counts.astype(final.dtype)
-        # expand pinned axes to size 1 for the slice write
-        shape = tuple(v.card if v in block_axes else 1 for v in keep)
-        block = block.reshape(shape)
-        if disjoint_blocks:
-            final = jax.lax.dynamic_update_slice(final, block, tuple(starts))
+def _embed(final: np.ndarray, keep: Tuple[CtVar, ...], A: Set[str],
+           table: CtTable) -> None:
+    """Add block-A of a complete table into ``final``: kept indicators
+    pinned to ``A``'s truth values, the edge axes of relations outside
+    ``A`` to their N/A slot.  A kept edge axis without its indicator
+    spans the N/A slot that the A-less block writes too, so blocks add."""
+    idx: List[object] = []
+    block_axes: List[CtVar] = []
+    for v in keep:
+        if v.kind == "rind":
+            idx.append(1 if v.owner[0] in A else 0)
+        elif v.kind == "edge" and v.owner[0] not in A:
+            idx.append(v.card - 1)              # N/A slot
         else:
-            idx = tuple(slice(st, st + sh) for st, sh in zip(starts, shape))
-            final = final.at[idx].add(block)
-
-    bp = _butterfly_plan(point, keep) if use_butterfly else None
-    if bp is not None:
-        # stack Y[c in {*,T}^k] = ct_+(T-set of c), butterfly to {F,T}^k;
-        # with no edge axes the complete table IS the transform output, up
-        # to axis order.
-        fn = mobius_fn or superset_mobius
-        stack = _butterfly_stack(point, bp, provider)
-        final = _butterfly_finalise(bp, fn(stack, bp.k)).counts
-    else:
-        for r_bits in itertools.product((0, 1), repeat=k):
-            A = {r for r, b in zip(effective, r_bits) if b == 1}
-            B = [r for r in effective if r not in A]
-            axes_A = kept_attrs + tuple(
-                v for r in sorted(A) for v in kept_edges.get(r, ()))
-            acc: Optional[jnp.ndarray] = None
-            for j in range(len(B) + 1):
-                for S in itertools.combinations(B, j):
-                    t = _pattern_table(point, A | set(S), axes_A, provider)
-                    contrib = t.transpose_to(axes_A).counts
-                    sign = -1.0 if j % 2 else 1.0
-                    acc = contrib * sign if acc is None else acc + sign * contrib
-            assert acc is not None
-            embed(A, CtTable(axes_A, acc))
-
-    tab = CtTable(keep, final)
-    if stats is not None:
-        stats.ct_cells += tab.size
-    return tab
+            idx.append(slice(None))
+            block_axes.append(v)
+    final[tuple(idx)] += table.transpose_to(tuple(block_axes)).counts
 
 
 def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
@@ -422,113 +370,55 @@ def complete_ct_many(queries: Sequence[Tuple[LatticePoint,
                      provider: PositiveProvider,
                      stats: Optional[CostStats] = None,
                      use_butterfly: bool = True,
-                     mobius_fn: Optional[Callable[[jnp.ndarray, int],
-                                                  jnp.ndarray]] = None,
-                     mobius_batch_fn: Optional[Callable[
-                         [Sequence[jnp.ndarray], int],
-                         List[jnp.ndarray]]] = None,
-                     mobius_fused_fn: Optional[Callable[
-                         [Sequence[Sequence[jnp.ndarray]], int,
-                          Tuple[int, ...]],
-                         List[jnp.ndarray]]] = None,
-                     tracer: NullTracer = NULL_TRACER) -> List[CtTable]:
-    """Complete ct-tables for many ``(point, keep)`` queries, with the
-    Möbius negative phase batched across same-shape butterfly stacks, all
-    inside one ``count.negative`` span on ``tracer``.
+                     tracer: NullTracer = NULL_TRACER,
+                     dtype=jnp.float32) -> List[CtTable]:
+    """Complete ct-tables for many ``(point, keep)`` queries, with one
+    block memo across them, all inside one ``count.negative`` span on
+    ``tracer``.  The span counts ``blocks``, the 2^k blocks of every
+    table's join, and ``blocks_built``, those projected and aligned on the
+    host: the difference is what the memo saved.
 
-    Butterfly-eligible queries (no kept edge-attr axes, ``k > 0``) are
-    grouped — same-signature families are same-shape by construction —
-    and each group runs ONE transform.  With ``mobius_fused_fn`` (normally
-    the executor's :meth:`~repro.core.executors.Executor
-    .mobius_batch_fused`) the groups are keyed by ``(attr shape, k,
-    finalise perm)`` and the *aligned blocks* go straight into the jitted
-    evaluator — stack assembly, transform AND the finalise transpose are
-    one dispatch per group, with per-query results sliced inside the jit.
-    Without it, stacks are assembled eagerly and ``mobius_batch_fn``
-    (normally :meth:`~repro.core.executors.Executor.mobius_batch`)
-    transforms each ``(stack shape, k)`` group, paying per-query glue.
-    Everything else (blockwise queries, ``k == 0``) falls back to
-    :func:`complete_ct` per query.
+    Butterfly-eligible queries (no kept edge-attr axes, ``k > 0``) read
+    their corner blocks through the memo — families of one point share
+    sub-patterns, so each distinct block is assembled once.  Everything
+    else (blockwise queries, ``k == 0``) is joined per query, as by
+    :func:`complete_ct`.
 
     Args:
-        queries: ``(point, keep)`` pairs; ``keep`` may contain attr and
-            rind axes of the point (edge-attr axes force the blockwise
-            fallback, exactly as in :func:`complete_ct`).
+        queries: ``(point, keep)`` pairs; ``keep`` may contain attr, edge
+            and rind axes of the point.
         provider: positive-table source (a policy from
             :mod:`repro.core.engine`).
         stats: optional :class:`~repro.core.contract.CostStats`;
             ``ct_cells`` accounting matches the per-query path.
-        use_butterfly / mobius_fn: as for :func:`complete_ct`.
-        mobius_batch_fn: batched transform ``(stacks, k) -> [stack]``;
-            defaults to :func:`butterfly_batch` over ``mobius_fn``.
-        mobius_fused_fn: fused batched transform ``(block_lists, k, perm)
-            -> [table array]``; preferred over ``mobius_batch_fn`` when
-            given.
+        use_butterfly: as for :func:`complete_ct`.
         tracer: the request tracer of the engine behind ``provider``.
+        dtype: the precision the join subtracts in, the engine's.
 
     Returns:
         One :class:`~repro.core.ct.CtTable` per query, positionally
-        aligned with ``queries`` and numerically identical to per-query
+        aligned with ``queries`` and identical to per-query
         :func:`complete_ct`.
 
     Usage::
 
-        tabs = complete_ct_many([(point, keep) for keep in keeps], policy,
-                                mobius_fused_fn=executor.mobius_batch_fused)
+        tabs = complete_ct_many([(point, keep) for keep in keeps], policy)
     """
     queries = [(point, tuple(keep)) for point, keep in queries]
     with tracer.span("count.negative") as sp:
+        memo: Dict = {}      # cross-query block reuse within this batch
+        tabs = [_complete_ct(point, keep, provider, stats, use_butterfly,
+                             dtype, memo) for point, keep in queries]
         if tracer.enabled:
-            sp.set(tables=len(queries))
-        return _complete_ct_many(queries, provider, stats, use_butterfly,
-                                 mobius_fn, mobius_batch_fn, mobius_fused_fn)
-
-
-def _complete_ct_many(queries, provider, stats, use_butterfly, mobius_fn,
-                      mobius_batch_fn, mobius_fused_fn) -> List[CtTable]:
-    if mobius_batch_fn is None:
-        mobius_batch_fn = lambda stacks, k: butterfly_batch(
-            stacks, k, mobius_fn)
-    results: List[Optional[CtTable]] = [None] * len(queries)
-    eligible: List[Tuple[int, _ButterflyPlan, List[jnp.ndarray]]] = []
-    memo: Dict = {}          # cross-query block reuse within this batch
-    for i, (point, keep) in enumerate(queries):
-        bp = _butterfly_plan(point, keep) if use_butterfly else None
-        if bp is None:
-            results[i] = _complete_ct(point, keep, provider, stats,
-                                      use_butterfly, mobius_fn)
-        else:
-            eligible.append((i, bp,
-                             _butterfly_blocks(point, bp, provider, memo)))
-    if mobius_fused_fn is not None:
-        groups: Dict[Tuple, List] = {}
-        for item in eligible:
-            _, bp, _ = item
-            attr_shape = tuple(v.card for v in bp.kept_attrs)
-            groups.setdefault((attr_shape, bp.k, bp.perm), []).append(item)
-        for (_, k, perm), members in groups.items():
-            outs = mobius_fused_fn([blks for _, _, blks in members], k,
-                                   perm)
-            for (i, bp, _), arr in zip(members, outs):
-                tab = CtTable(bp.keep, arr)     # already in request layout
-                if stats is not None:
-                    stats.ct_cells += tab.size
-                results[i] = tab
-        return results
-    groups2: Dict[Tuple, List[Tuple[int, _ButterflyPlan, jnp.ndarray]]] = {}
-    for i, bp, blks in eligible:
-        attr_shape = tuple(v.card for v in bp.kept_attrs)
-        stack = jnp.stack(blks).reshape((2,) * bp.k + attr_shape)
-        groups2.setdefault((tuple(stack.shape), bp.k), []).append(
-            (i, bp, stack))
-    for (_, k), members in groups2.items():
-        outs = mobius_batch_fn([s for _, _, s in members], k)
-        for (i, bp, _), out in zip(members, outs):
-            tab = _butterfly_finalise(bp, out)
-            if stats is not None:
-                stats.ct_cells += tab.size
-            results[i] = tab
-    return results
+            # blockwise joins (and butterflies off) build every block; a
+            # butterfly builds only its memo misses, one memo entry each
+            unshared = sum(_join_blocks(keep) for point, keep in queries
+                           if not use_butterfly
+                           or _butterfly_plan(point, keep) is None)
+            sp.set(tables=len(queries),
+                   blocks=sum(_join_blocks(keep) for _, keep in queries),
+                   blocks_built=unshared + len(memo))
+        return tabs
 
 
 # --------------------------------------------------------------------------
@@ -538,7 +428,7 @@ def _complete_ct_many(queries, provider, stats, use_butterfly, mobius_fn,
 
 def _butterfly_delta_blocks(point: LatticePoint, bp: _ButterflyPlan,
                             rel: str, provider: PositiveProvider,
-                            memo: Dict, zeros: Dict) -> List[jnp.ndarray]:
+                            memo: Dict) -> List[np.ndarray]:
     """Transform-input blocks of the COMPLETE-table *delta* for a write to
     ``rel``, in the same ``{*,T}^k`` corner order as
     :func:`_butterfly_blocks`.
@@ -547,43 +437,36 @@ def _butterfly_delta_blocks(point: LatticePoint, bp: _ButterflyPlan,
     corner set ``X`` true, so it depends on ``rel``'s edge table iff
     ``rel in X`` (atoms of other relations never enter the sub-pattern —
     see :func:`_pattern_table`).  Corners without ``rel`` therefore have an
-    exactly-zero delta and are materialised as explicit zero blocks;
-    corners with ``rel`` evaluate the SAME pattern assembly against a
-    *delta provider* (positives contracted over the
+    exactly-zero delta and are materialised as zero blocks; corners with
+    ``rel`` evaluate the SAME pattern assembly against a *delta provider*
+    (positives contracted over the
     :meth:`~repro.core.database.FactDelta.as_db` view), which by
     multilinearity yields the exact per-block delta as long as the point
     uses ``rel`` in exactly one atom (callers guard this).
 
-    ``memo``/``zeros`` are shared across a batch of queries: delta blocks
-    dedupe by sub-pattern exactly like the full path's blocks, and one
-    zero array serves every corner of a given ``(attr shape, dtype)``.
+    ``memo`` is shared across a batch of queries: delta blocks dedupe by
+    sub-pattern exactly like the full path's blocks.
     """
-    real: Dict[Tuple[int, ...], jnp.ndarray] = {}
-    corners = list(itertools.product((0, 1), repeat=bp.k))
-    for bits in corners:
+    zero = np.zeros(tuple(v.card for v in bp.kept_attrs))
+    blocks = []
+    for bits in itertools.product((0, 1), repeat=bp.k):
         X = {r for r, b in zip(bp.effective, bits) if b == 1}
         if rel not in X:
+            blocks.append(zero)
             continue
         mkey = (tuple(a for a in point.atoms if a.rel in X),
                 tuple(point.vars), bp.kept_attrs)
         blk = memo.get(mkey)
         if blk is None:
             t = _pattern_table(point, X, bp.kept_attrs, provider)
-            blk = t.transpose_to(bp.kept_attrs).counts
-            memo[mkey] = blk
-        real[bits] = blk
-    attr_shape = tuple(v.card for v in bp.kept_attrs)
-    dtype = next(iter(real.values())).dtype
-    zkey = (attr_shape, jnp.dtype(dtype).name)
-    zblk = zeros.get(zkey)
-    if zblk is None:
-        zblk = zeros[zkey] = jnp.zeros(attr_shape, dtype=dtype)
-    return [real.get(bits, zblk) for bits in corners]
+            blk = memo[mkey] = t.transpose_to(bp.kept_attrs).counts
+        blocks.append(blk)
+    return blocks
 
 
 def _blockwise_ct_delta(point: LatticePoint, keep: Tuple[CtVar, ...],
                         rel: str, provider: PositiveProvider,
-                        memo: Dict) -> CtTable:
+                        memo: Dict, dtype) -> CtTable:
     """Blockwise complete-table delta for queries the butterfly cannot
     serve (kept edge-attr axes need the N/A-slot block assembly).
 
@@ -604,16 +487,13 @@ def _blockwise_ct_delta(point: LatticePoint, keep: Tuple[CtVar, ...],
             kept_edges.setdefault(v.owner[0], []).append(v)
     kept_rinds = {v.owner[0] for v in keep if v.kind == "rind"}
     effective = sorted(set(kept_edges) | kept_rinds)
-    shape = tuple(v.card for v in keep)
-    final = jnp.zeros(shape, dtype=jnp.result_type(jnp.float32))
-    disjoint_blocks = all(r in kept_rinds for v in keep if v.kind == "edge"
-                          for r in [v.owner[0]])
+    final = np.zeros(tuple(v.card for v in keep))
     for r_bits in itertools.product((0, 1), repeat=len(effective)):
         A = {r for r, b in zip(effective, r_bits) if b == 1}
         B = [r for r in effective if r not in A]
         axes_A = kept_attrs + tuple(
             v for r in sorted(A) for v in kept_edges.get(r, ()))
-        acc: Optional[jnp.ndarray] = None
+        acc: Optional[np.ndarray] = None
         for j in range(len(B) + 1):
             for S in itertools.combinations(B, j):
                 X = A | set(S)
@@ -625,30 +505,11 @@ def _blockwise_ct_delta(point: LatticePoint, keep: Tuple[CtVar, ...],
                 if blk is None:
                     t = _pattern_table(point, X, axes_A, provider)
                     blk = memo[mkey] = t.transpose_to(axes_A).counts
-                sign = -1.0 if j % 2 else 1.0
-                acc = blk * sign if acc is None else acc + sign * blk
-        if acc is None:
-            continue                          # block independent of rel
-        starts: List[int] = []
-        block_axes: List[CtVar] = []
-        for v in keep:
-            if v.kind == "rind":
-                starts.append(1 if v.owner[0] in A else 0)
-            elif v.kind == "edge" and v.owner[0] not in A:
-                starts.append(v.card - 1)     # N/A slot
-            else:
-                starts.append(0)
-                block_axes.append(v)
-        aligned = CtTable(axes_A, acc).transpose_to(tuple(block_axes))
-        block = aligned.counts.astype(final.dtype)
-        bshape = tuple(v.card if v in block_axes else 1 for v in keep)
-        block = block.reshape(bshape)
-        if disjoint_blocks:
-            final = jax.lax.dynamic_update_slice(final, block,
-                                                 tuple(starts))
-        else:
-            idx = tuple(slice(s, s + sh) for s, sh in zip(starts, bshape))
-            final = final.at[idx].add(block)
+                acc = (blk if j % 2 == 0 else -blk) if acc is None else (
+                    _in_dtype(np.subtract if j % 2 else np.add, acc, blk,
+                              dtype))
+        if acc is not None:                   # else independent of rel
+            _embed(final, keep, A, CtTable(axes_A, acc))
     return CtTable(keep, final)
 
 
@@ -657,19 +518,10 @@ def complete_ct_delta_many(queries: Sequence[Tuple[LatticePoint,
                            rel: str,
                            provider: PositiveProvider,
                            stats: Optional[CostStats] = None,
-                           mobius_fn: Optional[Callable[
-                               [jnp.ndarray, int], jnp.ndarray]] = None,
-                           mobius_batch_fn: Optional[Callable[
-                               [Sequence[jnp.ndarray], int],
-                               List[jnp.ndarray]]] = None,
-                           mobius_fused_fn: Optional[Callable[
-                               [Sequence[Sequence[jnp.ndarray]], int,
-                                Tuple[int, ...]],
-                               List[jnp.ndarray]]] = None
+                           dtype=jnp.float32
                            ) -> List[Tuple[str, Optional[CtTable]]]:
     """Delta tables for many resident complete-CT queries after a write to
-    ``rel``, with the negative phase batched exactly like
-    :func:`complete_ct_many`.
+    ``rel``, with one block memo across them, as :func:`complete_ct_many`.
 
     The Möbius transform is linear in its input blocks, so the delta of a
     complete table is the transform of the per-block deltas — no resident
@@ -686,8 +538,7 @@ def complete_ct_delta_many(queries: Sequence[Tuple[LatticePoint,
         rel: the relationship the delta wrote.
         provider: delta-positive source (full-valued ``hist``; the engine
             wraps its policy in a view-backed provider).
-        stats / mobius_fn / mobius_batch_fn / mobius_fused_fn: as for
-            :func:`complete_ct_many`.
+        stats, dtype: as for :func:`complete_ct_many`.
 
     Returns:
         One ``(status, table)`` per query, positionally aligned:
@@ -709,16 +560,10 @@ def complete_ct_delta_many(queries: Sequence[Tuple[LatticePoint,
                 complete_ct_delta_many(q, delta.rel, delta_provider)):
             ...
     """
-    queries = [(point, tuple(keep)) for point, keep in queries]
-    if mobius_batch_fn is None:
-        mobius_batch_fn = lambda stacks, k: butterfly_batch(
-            stacks, k, mobius_fn)
-    results: List[Tuple[str, Optional[CtTable]]] = \
-        [("fallback", None)] * len(queries)
-    eligible: List[Tuple[int, _ButterflyPlan, List[jnp.ndarray]]] = []
+    results: List[Tuple[str, Optional[CtTable]]] = []
     memo: Dict = {}
-    zeros: Dict = {}
-    for i, (point, keep) in enumerate(queries):
+    for point, keep in queries:
+        keep = tuple(keep)
         bp = _butterfly_plan(point, keep)
         effective = bp.effective if bp is not None else tuple(
             {v.owner[0] for v in keep if v.kind in ("edge", "rind")})
@@ -726,59 +571,30 @@ def complete_ct_delta_many(queries: Sequence[Tuple[LatticePoint,
             # rel's indicator is summed out (or rel is not in the pattern
             # at all): every transform block is independent of rel's edge
             # table, so the resident value is already exact.
-            results[i] = ("zero", None)
+            results.append(("zero", None))
             continue
         if sum(1 for a in point.atoms if a.rel == rel) != 1:
-            continue                          # cross terms: fallback
+            results.append(("fallback", None))   # cross terms
+            continue
         if bp is None:
             # kept edge-attr axes: same linearity, blockwise assembly
-            tab = _blockwise_ct_delta(point, tuple(keep), rel, provider,
-                                      memo)
-            if stats is not None:
-                stats.ct_cells += tab.size
-            results[i] = ("delta", tab)
-            continue
-        eligible.append((i, bp, _butterfly_delta_blocks(
-            point, bp, rel, provider, memo, zeros)))
-    if mobius_fused_fn is not None:
-        groups: Dict[Tuple, List] = {}
-        for item in eligible:
-            _, bp, _ = item
-            attr_shape = tuple(v.card for v in bp.kept_attrs)
-            groups.setdefault((attr_shape, bp.k, bp.perm), []).append(item)
-        for (_, k, perm), members in groups.items():
-            outs = mobius_fused_fn([blks for _, _, blks in members], k,
-                                   perm)
-            for (i, bp, _), arr in zip(members, outs):
-                tab = CtTable(bp.keep, arr)   # already in request layout
-                if stats is not None:
-                    stats.ct_cells += tab.size
-                results[i] = ("delta", tab)
-        return results
-    groups2: Dict[Tuple, List[Tuple[int, _ButterflyPlan, jnp.ndarray]]] = {}
-    for i, bp, blks in eligible:
-        attr_shape = tuple(v.card for v in bp.kept_attrs)
-        stack = jnp.stack(blks).reshape((2,) * bp.k + attr_shape)
-        groups2.setdefault((tuple(stack.shape), bp.k), []).append(
-            (i, bp, stack))
-    for (_, k), members in groups2.items():
-        outs = mobius_batch_fn([s for _, _, s in members], k)
-        for (i, bp, _), out in zip(members, outs):
-            tab = _butterfly_finalise(bp, out)
-            if stats is not None:
-                stats.ct_cells += tab.size
-            results[i] = ("delta", tab)
+            tab = _blockwise_ct_delta(point, keep, rel, provider, memo,
+                                      dtype)
+        else:
+            tab = _butterfly_transform(bp, _butterfly_delta_blocks(
+                point, bp, rel, provider, memo), dtype)
+        if stats is not None:
+            stats.ct_cells += tab.size
+        results.append(("delta", tab))
     return results
 
 
 def butterfly_delta(point: LatticePoint, keep: Sequence[CtVar], rel: str,
                     provider: PositiveProvider,
                     stats: Optional[CostStats] = None,
-                    mobius_fn: Optional[Callable[[jnp.ndarray, int],
-                                                 jnp.ndarray]] = None
-                    ) -> Tuple[str, Optional[CtTable]]:
+                    dtype=jnp.float32) -> Tuple[str, Optional[CtTable]]:
     """Single-query convenience over :func:`complete_ct_delta_many` — the
     ``(status, delta table)`` for one resident complete-CT entry after a
     write to ``rel``."""
     return complete_ct_delta_many([(point, keep)], rel, provider, stats,
-                                  mobius_fn=mobius_fn)[0]
+                                  dtype)[0]

@@ -99,6 +99,12 @@ class ContractionPlan:
     def out_shape(self) -> Tuple[int, ...]:
         return tuple(v.card for v in self.keep)
 
+    @property
+    def hops(self) -> int:
+        """Relationship hops the plan walks: one per atom of the point
+        (the ``hops`` counter of ``count.positive`` spans)."""
+        return len(self.point.atoms)
+
     def shape_signature(self) -> Tuple[Tuple[str, int], ...]:
         """Batching key: plans with equal signatures yield same-shape
         ct-tables (axis kinds + cards, in output order)."""

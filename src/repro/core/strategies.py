@@ -15,9 +15,8 @@ over shared machinery (:mod:`repro.core.engine`): it picks a positive-table
 policy, decides what runs at ``prepare`` time vs. search time, and shares
 one byte-budgeted :class:`~repro.core.cache.CtCache` across positives,
 messages, family memos and histograms.  The contraction backend is
-pluggable (``executor="dense" | "sparse"``) and the Möbius negative phase
-runs through the executor (wired to the Pallas kernel with
-``use_pallas_mobius=True``, or any ``mobius_fn`` override).
+pluggable (``executor="dense" | "sparse"``); the Möbius negative phase
+runs on the host, subtracting in ``dtype`` (:mod:`repro.core.mobius`).
 
 * PRECOUNT — prepare() contracts the positive ct-table for every lattice
   point AND runs the Möbius join to the complete table over *all* variables
@@ -45,8 +44,7 @@ from .ct import CtTable
 from .database import RelationalDB
 from .engine import (CachedFullPositives, CountingEngine, OnDemandPositives,
                      TupleIdPositives)
-from .mobius import (butterfly_batch, complete_ct, complete_ct_many,
-                     positive_queries)
+from .mobius import complete_ct, complete_ct_many, positive_queries
 from .variables import CtVar, LatticePoint
 
 
@@ -66,11 +64,9 @@ class Strategy:
     name: str = "base"
     dtype: object = jnp.float32
     use_butterfly: bool = True
-    mobius_fn: Optional[object] = None     # overrides the executor's step
     stats: CostStats = field(default_factory=CostStats)
     executor: object = "dense"             # name or Executor instance
     cache_budget_bytes: Optional[int] = None
-    use_pallas_mobius: bool = False
 
     _policy_cls = None                     # set by subclasses
     _precount_complete = False             # PRECOUNT: complete tables upfront
@@ -83,8 +79,7 @@ class Strategy:
         the executor's tracer."""
         from .executors import make_executor
         ex = (self.executor if not isinstance(self.executor, str)
-              else make_executor(self.executor, dtype=self.dtype,
-                                 use_pallas_mobius=self.use_pallas_mobius))
+              else make_executor(self.executor, dtype=self.dtype))
         tr = ex.tracer
         with tr.span("strategy.prepare") as sp:
             if tr.enabled:
@@ -110,10 +105,6 @@ class Strategy:
                     self._complete_full(point)
 
     # -- complete tables -----------------------------------------------------
-    def _mobius_fn(self):
-        return self.mobius_fn if self.mobius_fn is not None \
-            else self.engine.executor.mobius
-
     def _timed_complete(self, point: LatticePoint,
                         keep: Tuple[CtVar, ...]) -> CtTable:
         """Möbius join timed as negative-phase work; positive contractions
@@ -123,8 +114,8 @@ class Strategy:
         with self.stats.disjoint_timer("negative"):
             return complete_ct(point, keep, self.provider, self.stats,
                                use_butterfly=self.use_butterfly,
-                               mobius_fn=self._mobius_fn(),
-                               tracer=self.engine.tracer)
+                               tracer=self.engine.tracer,
+                               dtype=self.engine.dtype)
 
     def _complete_full(self, point: LatticePoint) -> CtTable:
         """Complete (positive+negative) table over *all* axes of a point —
@@ -171,24 +162,6 @@ class Strategy:
                                                   positives=self.provider)
         return svc
 
-    def _mobius_batch_fn(self):
-        """The batched negative-phase step, honouring a ``mobius_fn``
-        override the same way :meth:`_mobius_fn` does."""
-        if self.mobius_fn is not None:
-            return lambda stacks, k: butterfly_batch(stacks, k,
-                                                     self.mobius_fn)
-        return self.engine.executor.mobius_batch
-
-    def _mobius_fused_fn(self):
-        """The FUSED batched negative phase (assembly + transform +
-        finalise in one jitted dispatch per shape/perm group).  A
-        ``mobius_fn`` override opts out: the fused evaluator traces the
-        executor's own step, so an ad-hoc override falls back to the
-        unfused batched path."""
-        if self.mobius_fn is not None:
-            return None
-        return self.engine.executor.mobius_batch_fused
-
     # -- mutations -----------------------------------------------------------
     def apply_delta(self, delta, **kw):
         """Reconcile this strategy's cache after a store mutation —
@@ -214,11 +187,9 @@ class Strategy:
         actually contract from data, and executed through the counting
         service in signature-bucketed stacked dispatches.  The *negative*
         phase of the missing families then runs through
-        :func:`~repro.core.mobius.complete_ct_many`: butterfly input
-        stacks are grouped by shape (same-signature families are
-        same-shape by construction) and each group is transformed in ONE
-        jitted dispatch (:meth:`~repro.core.executors.Executor
-        .mobius_batch`).  Results — including the recompute semantics
+        :func:`~repro.core.mobius.complete_ct_many`, whose block memo
+        assembles each sub-pattern block the round shares once.  Results
+        — including the recompute semantics
         under cache eviction — are numerically identical to per-family
         :meth:`family_ct`, which serves the final answers from the warmed
         ``"fam"`` cache."""
@@ -241,10 +212,7 @@ class Strategy:
                 tabs = complete_ct_many(
                     [(point, keep) for keep in missing], self.provider,
                     self.stats, use_butterfly=self.use_butterfly,
-                    mobius_fn=self._mobius_fn(),
-                    mobius_batch_fn=self._mobius_batch_fn(),
-                    mobius_fused_fn=self._mobius_fused_fn(),
-                    tracer=self.engine.tracer)
+                    tracer=self.engine.tracer, dtype=self.engine.dtype)
             for keep, tab in zip(missing, tabs):
                 cache.put(("fam",) + _freeze(point, keep), tab)
                 fresh[keep] = tab      # return directly: under a tight

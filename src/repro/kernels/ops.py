@@ -35,11 +35,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .mobius_kernel import mobius_pallas
 from .hist_kernel import segment_hist_pallas
 from .bdeu_kernel import bdeu_pallas
 from .segsum_kernel import segment_sum_ones_pallas, segment_sum_rows_pallas
-from .ref import mobius_ref, segment_hist_ref, bdeu_ref
+from .ref import segment_hist_ref, bdeu_ref
 
 # beyond this the O(edges x segments) one-hot sweep loses to XLA scatter
 SEGSUM_KERNEL_MAX_SEGMENTS = 1 << 15
@@ -81,29 +80,6 @@ def segsum_kernel_enabled(num_segments: int) -> bool:
     if forced is not None:
         return forced
     return _on_accelerator()
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _mobius(stack: jnp.ndarray, interpret: bool) -> jnp.ndarray:
-    return mobius_pallas(stack, interpret=interpret)
-
-
-def mobius(stack: jnp.ndarray,
-           interpret: Optional[bool] = None) -> jnp.ndarray:
-    return _mobius(stack, interpret=_resolve(interpret))
-
-
-def mobius_nd(stack: jnp.ndarray, k: int,
-              interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Adapter matching `repro.core.mobius.superset_mobius`'s (2,)*k + attrs
-    signature, so the kernel can be plugged in as ``Strategy.mobius_fn``."""
-    lead = stack.shape[:k]
-    tail = stack.shape[k:]
-    import numpy as np
-    d = int(np.prod(tail)) if tail else 1
-    flat = stack.reshape((1 << k), d)
-    out = mobius(flat, interpret=interpret)
-    return out.reshape(lead + tail)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))

@@ -7,20 +7,6 @@ import jax.numpy as jnp
 from jax.scipy.special import gammaln
 
 
-def mobius_ref(stack: jnp.ndarray) -> jnp.ndarray:
-    """Superset Möbius transform on a [R=2^k, D] stack: replace the
-    "unconstrained" slot (bit=0) with "false" via x0 <- x0 - x1 per bit."""
-    r, d = stack.shape
-    k = r.bit_length() - 1
-    assert 1 << k == r, "leading dim must be a power of two"
-    x = stack.reshape((2,) * k + (d,))
-    for i in range(k):
-        x0 = jnp.take(x, 0, axis=i) - jnp.take(x, 1, axis=i)
-        x1 = jnp.take(x, 1, axis=i)
-        x = jnp.stack([x0, x1], axis=i)
-    return x.reshape(r, d)
-
-
 def segment_hist_ref(codes: jnp.ndarray, values: jnp.ndarray,
                      num_segments: int) -> jnp.ndarray:
     """Weighted histogram / segment-sum: out[p, d] = sum_{n: codes[n]=p} values[n, d]."""

@@ -17,9 +17,8 @@ queries** (positive + Möbius negative phase): the positive sub-queries of
 every complete query are enumerated up front
 (:func:`~repro.core.mobius.positive_queries`), deduplicated through the
 positive policy, and executed via :func:`execute_bucketed`; the negative
-phase then runs through :func:`~repro.core.mobius.complete_ct_many`,
-which groups same-shape butterfly stacks and transforms each group in ONE
-jitted dispatch (:meth:`~repro.core.executors.Executor.mobius_batch`).
+phase then runs through :func:`~repro.core.mobius.complete_ct_many`, on
+the host in the engine's dtype, with one block memo across the batch.
 """
 
 from __future__ import annotations
@@ -174,7 +173,7 @@ def execute_bucketed(executor: Executor, db: RelationalDB,
             that receives one ``observe_batch`` per micro-batch.
         tracer: optional :class:`~repro.obs.trace.Tracer`; the whole
             call is one ``count.positive`` span (``tables``: the plans
-            contracted) and each micro-batch dispatch a ``batch.dispatch``
+            contracted; ``hops``: their relationship hops) and each micro-batch dispatch a ``batch.dispatch``
             span inside it.
 
     Returns:
@@ -186,7 +185,7 @@ def execute_bucketed(executor: Executor, db: RelationalDB,
     """
     with tracer.span("count.positive") as sp:
         if tracer.enabled:
-            sp.set(tables=len(plans))
+            sp.set(tables=len(plans), hops=sum(p.hops for p in plans))
         results: List[Optional[CtTable]] = [None] * len(plans)
         for sig, idxs in group_by_signature(plans, key="shape").items():
             step = max_batch_size if max_batch_size else len(idxs)
@@ -249,7 +248,7 @@ def execute_bucketed_multi(executor: Executor,
     """
     with tracer.span("count.positive") as sp:
         if tracer.enabled:
-            sp.set(tables=len(plans))
+            sp.set(tables=len(plans), hops=sum(p.hops for p in plans))
         results: List[Optional[CtTable]] = [None] * len(plans)
         for sig, idxs in group_by_signature(plans, key="shape").items():
             step = max_batch_size if max_batch_size else len(idxs)
@@ -295,9 +294,8 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
     from data (:meth:`~repro.core.engine._Policy.batchable_misses`),
     executed through :func:`execute_bucketed` in signature-bucketed
     stacked dispatches, and absorbed back into the policy's cache.  Phase
-    2 (negative): :func:`~repro.core.mobius.complete_ct_many` assembles
-    each query's butterfly stack from the warmed cache and transforms
-    same-shape groups in one jitted dispatch each.
+    2 (negative): :func:`~repro.core.mobius.complete_ct_many` joins every
+    query on the host from the warmed cache, one block memo across them.
 
     Results align positionally with ``queries`` and are numerically
     identical to per-query :func:`~repro.core.mobius.complete_ct`.  Time
@@ -318,8 +316,8 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
         max_batch_size: positive-phase micro-batch cap (see
             :func:`execute_bucketed`).
         metrics: optional :class:`~repro.serve.metrics.ServiceMetrics`;
-            receives ``observe_batch`` per positive micro-batch and
-            ``observe_mobius`` per batched transform dispatch.
+            receives ``observe_batch`` per positive micro-batch and one
+            ``observe_mobius`` for the negative phase.
         use_butterfly: evaluation order, as in
             :func:`~repro.core.mobius.complete_ct`.
 
@@ -351,30 +349,15 @@ def execute_complete_bucketed(engine: CountingEngine, policy,
             for (p, _), plan, tab in zip(todo, plans, tabs):
                 policy.absorb(p, plan.keep, tab)
 
-        # the engine's fused evaluator always exists, so every
-        # butterfly-eligible query takes the fused path; blockwise queries
-        # fall back to per-query complete_ct over mobius_fn
-        fused_fn = engine.mobius_fused_fn()
-        if metrics is not None or tracer.enabled:
-            inner_fused = fused_fn
-            _metrics = metrics
-
-            def fused_fn(blocks, k, perm):
-                with (tracer.span("mobius.dispatch", stacks=len(blocks), k=k)
-                      if tracer.enabled else nullcontext()):
-                    t0 = time.perf_counter()
-                    out = inner_fused(blocks, k, perm)
-                    dt = time.perf_counter() - t0
-                if _metrics is not None:
-                    _metrics.observe_mobius(len(blocks), dt)
-                return out
-
         # any residual data access (unwarmed misses, eviction recomputes)
         # times itself in the policy; the disjoint timer subtracts its
         # growth to keep the Fig. 3 decomposition disjoint
+        t0 = time.perf_counter()
         with (stats.disjoint_timer("negative") if stats is not None
               else nullcontext()):
-            return complete_ct_many(queries, policy, stats,
+            tabs = complete_ct_many(queries, policy, stats,
                                     use_butterfly=use_butterfly,
-                                    mobius_fn=engine.mobius_fn(),
-                                    mobius_fused_fn=fused_fn, tracer=tracer)
+                                    tracer=tracer, dtype=engine.dtype)
+        if metrics is not None:
+            metrics.observe_mobius(len(queries), time.perf_counter() - t0)
+        return tabs

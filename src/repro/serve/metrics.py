@@ -170,9 +170,9 @@ class ServiceMetrics(_LockedMetrics):
     backpressure_flushes: int = 0  # triggered by in-flight/byte limits
     batches: int = 0              # positive_batch dispatches
     batched_queries: int = 0      # queries that went through a batch dispatch
-    mobius_batches: int = 0       # batched negative-phase (Möbius) dispatches
-    mobius_stacked: int = 0       # butterfly stacks transformed through them
-    mobius_exec_s: float = 0.0    # total batched-transform wall time
+    mobius_batches: int = 0       # negative-phase (Möbius) passes, one a batch
+    mobius_stacked: int = 0       # complete tables joined through them
+    mobius_exec_s: float = 0.0    # total negative-phase wall time
     exec_s: float = 0.0           # total bucket execution wall time
     wait_s: float = 0.0           # total queue residency across requests
     deltas: int = 0               # apply_delta() reconciliations
@@ -190,8 +190,8 @@ class ServiceMetrics(_LockedMetrics):
                                   repr=False, compare=False)
 
     def observe_mobius(self, n_stacks: int, dt: float) -> None:
-        """Record one batched negative-phase dispatch covering
-        ``n_stacks`` same-shape butterfly stacks."""
+        """Record one negative-phase pass over a batch of ``n_stacks``
+        complete tables."""
         with self._lock:
             self.mobius_batches += 1
             self.mobius_stacked += n_stacks

@@ -591,7 +591,8 @@ class CountingRouter:
                     t0 = time.perf_counter()
                     with self.tracer.span("count.positive") as sp:
                         if self.tracer.enabled:
-                            sp.set(tables=len(gplans))
+                            sp.set(tables=len(gplans),
+                                   hops=sum(p.hops for p in gplans))
                         merged = ex0.positive_fanout_merged(
                             dbs, gplans, sdb.partitioned, stats)
                     dt = time.perf_counter() - t0
@@ -711,7 +712,8 @@ class CountingRouter:
                     sp = timers.enter_context(
                         self.tracer.span("count.positive"))
                     if self.tracer.enabled:
-                        sp.set(tables=len(plans))
+                        sp.set(tables=len(plans),
+                               hops=sum(p.hops for p in plans))
                     per_shard, merged = ex0.positive_stacked_merged(
                         dbs, exs, plans, stats)
                 dt = time.perf_counter() - t0
@@ -911,15 +913,12 @@ class CountingRouter:
             # through one device reduction per shape group
             self._resolve_many(tickets)
             provider = _MergedProvider(self, engines[0])
-            # front-end negative phase, batched: same-shape butterfly
-            # stacks across ALL queries transform in one jitted dispatch
-            # each (mirrors the in-service complete path)
+            # front-end negative phase over ALL queries, one block memo
+            # across them (mirrors the in-service complete path)
             tabs = complete_ct_many(
                 [norm[i] for i in todo], provider,
-                use_butterfly=True,
-                mobius_fn=engines[0].mobius_fn(),
-                mobius_fused_fn=engines[0].mobius_fused_fn(),
-                tracer=self.tracer)
+                use_butterfly=True, tracer=self.tracer,
+                dtype=engines[0].dtype)
             for i, tab in zip(todo, tabs):
                 point, keep = norm[i]
                 self._settle(("complete", point.atoms, keep), tab, epoch)

@@ -36,12 +36,10 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np
     from jax.sharding import Mesh
-    from repro.core import positive_ct, point_from_rels, superset_mobius
+    from repro.core import positive_ct, point_from_rels
     from repro.core.distributed import (ShardedSparseExecutor,
                                         sharded_positive_ct,
-                                        sharded_sparse_positive_ct,
-                                        superset_mobius_sharded)
-    import jax.numpy as jnp
+                                        sharded_sparse_positive_ct)
     from tests.test_counting_core import tiny_db
 
     db = tiny_db(4)
@@ -56,10 +54,6 @@ SCRIPT = textwrap.dedent("""
         c = sharded_sparse_positive_ct(db, point, keep, mesh=mesh)
         np.testing.assert_allclose(np.asarray(a.counts), np.asarray(c.counts),
                                    atol=1e-3)
-    x = jnp.arange(2 * 2 * 16, dtype=jnp.float32).reshape(2, 2, 16)
-    with jax.set_mesh(mesh):
-        y = superset_mobius_sharded(x, 2, mesh=mesh)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(superset_mobius(x, 2)))
     print("DISTRIBUTED-OK")
 """)
 

@@ -8,36 +8,6 @@ pytest.importorskip("hypothesis", reason="hypothesis not installed in this conta
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.mobius_kernel import mobius_matrix
-
-
-# ---------------------------------------------------------------- mobius ---
-@pytest.mark.parametrize("k", [1, 2, 3, 4, 6])
-@pytest.mark.parametrize("d", [1, 7, 128, 300])
-def test_mobius_kernel_matches_ref(k, d):
-    rng = np.random.default_rng(k * 100 + d)
-    x = jnp.asarray(rng.integers(0, 50, size=(1 << k, d)).astype(np.float32))
-    got = ops.mobius(x)
-    want = ref.mobius_ref(x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
-
-
-def test_mobius_matrix_is_involution_signed():
-    # zeta (superset-sum) matrix is the inverse of the Möbius matrix
-    k = 4
-    t = mobius_matrix(k)
-    zeta = np.abs(t)  # zeta[A,S] = 1 iff S >= A
-    np.testing.assert_allclose(t @ zeta, np.eye(1 << k), atol=1e-6)
-
-
-@settings(max_examples=25, deadline=None)
-@given(k=st.integers(1, 5), seed=st.integers(0, 10_000))
-def test_mobius_kernel_property(k, seed):
-    rng = np.random.default_rng(seed)
-    d = int(rng.integers(1, 64))
-    x = jnp.asarray(rng.uniform(0, 100, size=(1 << k, d)).astype(np.float32))
-    np.testing.assert_allclose(np.asarray(ops.mobius(x)),
-                               np.asarray(ref.mobius_ref(x)), atol=1e-3)
 
 
 # ------------------------------------------------------------- histogram ---
@@ -95,20 +65,3 @@ def test_bdeu_kernel_matches_core_scorer():
     np.testing.assert_allclose(float(ops.bdeu(nijk, ess=1.0)),
                                float(bdeu_score_2d(nijk, ess=1.0)),
                                rtol=1e-4, atol=1e-2)
-
-
-# --------------------------------------------------- kernel-in-engine glue ---
-def test_mobius_kernel_pluggable_into_complete_ct():
-    from repro.core import complete_ct, point_from_rels, CostStats
-    from repro.core.strategies import _OnDemandProvider
-    from repro.core.oracle import oracle_ct
-    from tests.test_counting_core import tiny_db
-    db = tiny_db(5)
-    point = point_from_rels(db.schema, ["Reg", "RA"])
-    from repro.core.variables import Var
-    from repro.core import attr_var, rind_var
-    keep = (attr_var(Var("s"), "iq", 2), rind_var("Reg"), rind_var("RA"))
-    got = complete_ct(point, keep, _OnDemandProvider(db, CostStats()),
-                      use_butterfly=True, mobius_fn=ops.mobius_nd)
-    want = oracle_ct(db, point, keep)
-    np.testing.assert_allclose(np.asarray(got.counts), want, atol=1e-3)

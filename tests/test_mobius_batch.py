@@ -1,16 +1,15 @@
-"""Batched Möbius negative phase == unbatched == oracle.
+"""The Möbius negative phase, batched and per query, on the host.
 
 Three layers are pinned down:
 
-* the pure transform: :func:`repro.core.mobius.butterfly_batch` and the
-  executors' jitted :meth:`~repro.core.executors.Executor.mobius_batch`
-  are bit-identical to per-stack :func:`~repro.core.mobius
-  .superset_mobius` (including the Pallas kernel path and non-power-of-two
-  batch sizes, which exercise the padding);
+* the transform: :func:`repro.core.mobius.superset_mobius` equals the
+  Möbius matrix, superset sums invert it, and it subtracts in the dtype it
+  is given while the all-true corner keeps its input;
 * the assembly: :func:`repro.core.mobius.complete_ct_many` equals
   per-query :func:`~repro.core.mobius.complete_ct` under BOTH evaluation
-  orders (butterfly and blockwise);
-* the strategies: ``family_ct_many`` (which now routes whole rounds
+  orders (butterfly and blockwise), hands back float64 host tables equal
+  to the brute-force oracle, and reads each shared block once;
+* the strategies: ``family_ct_many`` (which routes whole rounds
   through the batched negative phase) == per-family ``family_ct`` ==
   brute-force oracle for all four strategies × both executors, including
   ``k == 0`` keeps (no indicator axes — nothing to transform) and card-1
@@ -21,14 +20,15 @@ import itertools
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 
 from repro.core import (CostStats, CountingEngine, build_lattice,
-                        butterfly_batch, complete_ct, complete_ct_many,
-                        make_strategy, superset_mobius)
-from repro.core.engine import OnDemandPositives
-from repro.core.executors import make_executor
+                        complete_ct, complete_ct_many, make_strategy,
+                        superset_mobius)
+from repro.core.engine import (CachedFullPositives, OnDemandPositives,
+                               TupleIdPositives)
 from repro.core.oracle import oracle_ct
 from repro.core.strategies import STRATEGIES
 from tests.test_executor_edge_cases import edge_case_db
@@ -41,60 +41,66 @@ STRAT_X_EXEC = list(itertools.product(sorted(STRATEGIES),
 
 # ------------------------------------------------------------ transform ----
 
-def _random_stacks(rng, b, k, attr_shape):
-    return [jnp.asarray(rng.integers(0, 50, size=(2,) * k + attr_shape)
-                        .astype(np.float32)) for _ in range(b)]
+def _random_stack(rng, k, attr_shape, high=50):
+    return rng.integers(0, high, size=(2,) * k + attr_shape).astype(
+        np.float64)
 
 
-@pytest.mark.parametrize("b,k,attr_shape", [
-    (1, 1, (3,)), (2, 2, (3, 2)), (3, 1, ()), (5, 3, (4,)), (8, 2, (2, 1)),
-])
-def test_butterfly_batch_equals_per_stack(b, k, attr_shape):
-    rng = np.random.default_rng(b * 10 + k)
-    stacks = _random_stacks(rng, b, k, attr_shape)
-    want = [superset_mobius(s, k) for s in stacks]
-    got = butterfly_batch(stacks, k)
-    assert len(got) == b
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+def _mobius_matrix(k):
+    """T[A, S] = (-1)^(|S|-|A|) for S a superset of A (bit 1 = relation
+    true), else 0: the transform as a matrix on the flattened corners."""
+    n = 1 << k
+    return np.array([[(-1) ** bin(s ^ a).count("1") if s & a == a else 0
+                      for s in range(n)] for a in range(n)], np.float64)
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_executor_mobius_batch_identical_to_mobius(use_pallas):
-    """The jitted batched step == the per-stack step, for the pure-jnp
-    mirror and the Pallas kernel, across batch sizes that do and do not
-    hit the power-of-two padding."""
-    ex = make_executor("sparse", use_pallas_mobius=use_pallas)
-    rng = np.random.default_rng(7)
-    for b, k, attr_shape in ((1, 1, (3,)), (3, 2, (2, 3)), (4, 1, (5,)),
-                             (7, 2, ())):
-        stacks = _random_stacks(rng, b, k, attr_shape)
-        want = [ex.mobius(s, k) for s in stacks]
-        got = ex.mobius_batch(stacks, k)
-        for w, g in zip(want, got):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       atol=1e-4)
-    assert ex.mobius_batch([], 1) == []
+@pytest.mark.parametrize("k", [1, 2, 3, 4, 6])
+@pytest.mark.parametrize("d", [1, 7, 128, 300])
+def test_host_transform_matches_the_mobius_matrix(k, d):
+    stack = _random_stack(np.random.default_rng(k * 100 + d), k, (d,))
+    got = superset_mobius(stack, k, np.float64)
+    assert isinstance(got, np.ndarray) and got.shape == stack.shape
+    np.testing.assert_array_equal(
+        got.reshape(1 << k, d),
+        _mobius_matrix(k) @ stack.reshape(1 << k, d))
 
 
-def test_mobius_batch_jit_cache_is_keyed_by_shape():
-    ex = make_executor("dense")
-    rng = np.random.default_rng(3)
-    ex.mobius_batch(_random_stacks(rng, 3, 1, (2,)), 1)
-    n_keys = len(ex._batch_cache)
-    ex.mobius_batch(_random_stacks(rng, 4, 1, (2,)), 1)   # same pad bucket
-    assert len(ex._batch_cache) == n_keys
-    ex.mobius_batch(_random_stacks(rng, 3, 2, (2,)), 2)   # new shape
-    assert len(ex._batch_cache) == n_keys + 1
+@settings(max_examples=25, deadline=None)
+@given(k=st.integers(1, 5), seed=st.integers(0, 10_000))
+def test_superset_sums_invert_the_transform(k, seed):
+    rng = np.random.default_rng(seed)
+    stack = _random_stack(rng, k, (int(rng.integers(1, 64)),))
+    got = superset_mobius(stack, k, np.float64).reshape(1 << k, -1)
+    zeta = np.abs(_mobius_matrix(k))          # zeta[A, S] = 1 iff S >= A
+    np.testing.assert_array_equal(zeta @ got, stack.reshape(1 << k, -1))
+
+
+@pytest.mark.parametrize("dtype", [np.float64, jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_host_transform_subtracts_in_dtype(dtype, k):
+    """Counts past 2**24: the all-true corner, which no subtraction
+    writes, keeps the input exactly; every other corner is a value of
+    ``dtype`` within its rounding of the exact transform."""
+    stack = _random_stack(np.random.default_rng(k), k, (3, 2),
+                          high=2 ** 26) + 2 ** 25
+    exact = superset_mobius(stack, k, np.float64)
+    got = superset_mobius(stack, k, dtype)
+    assert got.dtype == np.float64
+    top = (1,) * k
+    np.testing.assert_array_equal(got[top], stack[top])
+    dt = jnp.dtype(dtype)
+    rest = got.reshape((1 << k,) + got.shape[k:])[:-1]     # all but top
+    np.testing.assert_array_equal(rest, rest.astype(dt).astype(np.float64))
+    eps = float(jnp.finfo(dt).eps)
+    assert np.max(np.abs(got - exact)) <= 2 * k * eps * np.max(stack)
+    if dt != np.float64:
+        assert np.any(got != exact)
 
 
 # ------------------------------------------------------------- assembly ----
 
-@pytest.mark.parametrize("ex", ["dense", "sparse"])
-def test_complete_ct_many_equals_complete_ct_both_orders(ex):
-    db = mixed_db()
+def _queries(db, rng, n_random):
     lattice = build_lattice(db.schema, 2)
-    rng = np.random.default_rng(5)
     queries = []
     for point in (lattice[0], lattice[-1]):
         pool = list(point.all_ct_vars(db.schema, include_rind=True))
@@ -102,82 +108,84 @@ def test_complete_ct_many_equals_complete_ct_both_orders(ex):
         queries.append((point, ()))                       # k == 0, scalar
         queries.append((point, tuple(v for v in pool
                                      if v.kind == "attr")))  # k == 0
-        for _ in range(3):
+        for _ in range(n_random):
             k = rng.integers(1, len(pool) + 1)
             pick = rng.choice(len(pool), size=k, replace=False)
             queries.append((point, tuple(pool[i] for i in sorted(pick))))
+    return queries
 
+
+@pytest.mark.parametrize("ex", ["dense", "sparse"])
+def test_complete_ct_many_equals_complete_ct_both_orders(ex):
+    db = mixed_db()
+    queries = _queries(db, np.random.default_rng(5), 3)
     for use_butterfly in (True, False):
         eng = CountingEngine(db, ex, CostStats())
         policy = OnDemandPositives(eng)
-        got = complete_ct_many(queries, policy, use_butterfly=use_butterfly,
-                               mobius_batch_fn=eng.executor.mobius_batch)
+        got = complete_ct_many(queries, policy, use_butterfly=use_butterfly)
         ref_eng = CountingEngine(db, ex, CostStats())
         ref_policy = OnDemandPositives(ref_eng)
         for (point, keep), g in zip(queries, got):
             want = complete_ct(point, keep, ref_policy,
                                use_butterfly=use_butterfly)
             assert g.vars == want.vars
-            np.testing.assert_allclose(
-                np.asarray(g.counts), np.asarray(want.counts), atol=1e-3,
+            np.testing.assert_array_equal(
+                np.asarray(g.counts), np.asarray(want.counts),
                 err_msg=f"{ex} butterfly={use_butterfly} "
                         f"keep={[str(v) for v in keep]}")
 
 
-@pytest.mark.parametrize("ex,use_pallas", [("dense", False),
-                                           ("sparse", False),
-                                           ("sparse", True)])
-def test_complete_ct_many_fused_equals_unfused(ex, use_pallas):
-    """The FUSED batched path (stack assembly + transform + finalise
-    transpose in one jitted dispatch per (shape, perm) group) is
-    bit-identical to the unfused batched path and per-query complete_ct,
-    for the pure-jnp step and the Pallas kernel."""
+@pytest.mark.parametrize("policy_cls", [OnDemandPositives,
+                                        CachedFullPositives,
+                                        TupleIdPositives])
+def test_complete_tables_are_exact_float64_on_the_host(policy_cls):
+    """Whatever serves the positives, the joined tables are NumPy float64
+    and equal the brute-force oracle exactly."""
     db = mixed_db()
+    queries = _queries(db, np.random.default_rng(9), 4)
     lattice = build_lattice(db.schema, 2)
-    rng = np.random.default_rng(9)
-    queries = []
-    for point in (lattice[0], lattice[-1]):
-        pool = list(point.all_ct_vars(db.schema, include_rind=True))
-        queries.append((point, tuple(pool)))
-        queries.append((point, ()))                       # k == 0 fallback
-        for _ in range(4):
-            k = rng.integers(1, len(pool) + 1)
-            pick = rng.choice(len(pool), size=k, replace=False)
-            queries.append((point, tuple(pool[i] for i in sorted(pick))))
-    executor = make_executor(ex, use_pallas_mobius=use_pallas)
-    eng = CountingEngine(db, executor, CostStats())
-    got = complete_ct_many(queries, OnDemandPositives(eng),
-                           mobius_fused_fn=executor.mobius_batch_fused)
-    ref_eng = CountingEngine(db, ex, CostStats())
-    ref_policy = OnDemandPositives(ref_eng)
-    unfused = complete_ct_many(queries, ref_policy,
-                               mobius_batch_fn=ref_eng.executor.mobius_batch)
-    for (point, keep), g, u in zip(queries, got, unfused):
-        want = complete_ct(point, keep, ref_policy)
-        assert g.vars == want.vars
-        np.testing.assert_allclose(
-            np.asarray(g.counts), np.asarray(want.counts), atol=1e-3,
-            err_msg=f"{ex}/pallas={use_pallas} keep={[str(v) for v in keep]}")
-        np.testing.assert_allclose(np.asarray(g.counts),
-                                   np.asarray(u.counts), atol=1e-3)
+    policy = policy_cls(CountingEngine(db, "sparse", CostStats()))
+    policy.precompute(lattice)
+    for (point, keep), g in zip(queries, complete_ct_many(queries, policy)):
+        assert isinstance(g.counts, np.ndarray)
+        assert g.counts.dtype == np.float64
+        np.testing.assert_array_equal(
+            g.counts, oracle_ct(db, point, keep),
+            err_msg=f"{policy_cls.__name__} keep={[str(v) for v in keep]}")
 
 
-def test_mobius_batch_fused_one_dispatch_per_group():
-    """All queries of one (shape, perm) group share ONE jit entry, and
-    padding keeps the cache keyed by a handful of batch sizes."""
-    ex = make_executor("sparse")
-    rng = np.random.default_rng(4)
-    blocks = lambda b, k, shp: [[jnp.asarray(
-        rng.integers(0, 9, size=shp).astype(np.float32))
-        for _ in range(1 << k)] for _ in range(b)]
-    perm = (0, 1)
-    ex.mobius_batch_fused(blocks(3, 1, (2,)), 1, perm)
-    n_keys = len(ex._batch_cache)
-    ex.mobius_batch_fused(blocks(4, 1, (2,)), 1, perm)    # same pad bucket
-    assert len(ex._batch_cache) == n_keys
-    ex.mobius_batch_fused(blocks(3, 1, (2,)), 1, (1, 0))  # new perm group
-    assert len(ex._batch_cache) == n_keys + 1
-    assert ex.mobius_batch_fused([], 1, perm) == []
+def test_complete_ct_many_reads_each_shared_block_once():
+    """Families of one point share sub-pattern blocks: the batch asks the
+    provider for fewer tables than the queries joined one by one."""
+    db = mixed_db()
+    point = build_lattice(db.schema, 2)[-1]
+    pool = [v for v in point.all_ct_vars(db.schema, include_rind=True)
+            if v.kind != "edge"]
+    rinds = tuple(v for v in pool if v.kind == "rind")
+    attr = next(v for v in pool if v.kind == "attr")
+    assert len(rinds) >= 2
+    keeps = [(attr, r) for r in rinds] + [(attr,) + rinds]
+
+    class Counting:
+        def __init__(self):
+            self.inner = OnDemandPositives(
+                CountingEngine(db, "sparse", CostStats()))
+            self.calls = 0
+
+        def positive(self, p, keep):
+            self.calls += 1
+            return self.inner.positive(p, keep)
+
+        def hist(self, var, keep):
+            self.calls += 1
+            return self.inner.hist(var, keep)
+
+    batched, single = Counting(), Counting()
+    got = complete_ct_many([(point, k) for k in keeps], batched)
+    for keep, g in zip(keeps, got):
+        np.testing.assert_array_equal(
+            g.counts, complete_ct(point, keep, single).counts)
+    assert 0 < batched.calls < single.calls
 
 
 # ------------------------------------------------------------ strategies ----

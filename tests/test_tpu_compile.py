@@ -20,7 +20,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bdeu_kernel import bdeu_pallas
 from repro.kernels.hist_kernel import segment_hist_pallas
-from repro.kernels.mobius_kernel import mobius_pallas
 from repro.kernels.segsum_kernel import (segment_sum_ones_pallas,
                                          segment_sum_rows_pallas)
 
@@ -36,8 +35,6 @@ CASES = {
     "segsum_ones_1.9M_edges": (
         segment_sum_ones_pallas, dict(num_segments=32768),
         [((1_900_000,), I32), ((1_900_000,), F32)]),
-    "mobius_k2": (mobius_pallas, {}, [((4, 4096), F32)]),
-    "mobius_k8": (mobius_pallas, {}, [((256, 4096), F32)]),
     "segment_hist": (segment_hist_pallas, dict(num_segments=1000),
                      [((5000,), I32), ((5000, 48), F32)]),
     # Q > block_q: several Q-blocks, one partial tile each
