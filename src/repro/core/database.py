@@ -40,6 +40,10 @@ from .schema import Attribute, EntityType, Relationship, Schema
 class EntityTable:
     type: EntityType
     attrs: Dict[str, np.ndarray]      # name -> int32[size]
+    # in-place writes so far (``update_attrs``); the table is shared by
+    # the stores that hold it (delta views, shards), whose version numbers
+    # do not order its writes
+    writes: int = 0
 
     @property
     def size(self) -> int:
@@ -342,6 +346,7 @@ class RelationalDB:
         old_vals = {name: tab.attrs[name][rows].copy() for name in attrs}
         for name, col in attrs.items():
             tab.attrs[name][rows] = col
+        tab.writes += 1
         old, self.version = self.version, self.version + 1
         return AttrDelta(etype, rows, old_vals, attrs, old, self.version)
 

@@ -59,6 +59,15 @@ def _pad_to(arr: np.ndarray, mult: int) -> Tuple[np.ndarray, np.ndarray]:
     return arr, w
 
 
+def _pad_rows(arr: jax.Array, mult: int) -> Tuple[jax.Array, jax.Array]:
+    """:func:`_pad_to` for a device array, padded on the device: axis 0
+    to a multiple of ``mult`` with zeros; returns (padded, weight_mask)."""
+    n = int(arr.shape[0])
+    pad = -n % mult
+    w = jnp.pad(jnp.ones((n,), dtype=jnp.float32), (0, pad))
+    return jnp.pad(arr, ((0, pad),) + ((0, 0),) * (arr.ndim - 1)), w
+
+
 def _unsharded(x: jax.Array) -> jax.Array:
     """``x`` as a plain array on the first device of its mesh, with no mesh
     in its type: the form in which every table leaves a sharded hop.
@@ -395,12 +404,12 @@ class ShardedSparseExecutor(SparseExecutor):
                                  out_specs=P(None, None), check_vma=False))
 
     # -- device primitives, sharded -----------------------------------------
-    def _edge_segment_sum(self, seg_np: np.ndarray,
+    def _edge_segment_sum(self, seg: jax.Array,
                           rows: Optional[jnp.ndarray],
                           total: int) -> jnp.ndarray:
         if self.n_ranks == 1 or self._force_local:
-            return super()._edge_segment_sum(seg_np, rows, total)
-        seg, w = _pad_to(seg_np, self.n_ranks)
+            return super()._edge_segment_sum(seg, rows, total)
+        seg, w = _pad_rows(seg, self.n_ranks)
         if rows is None:
             fn = self._shard_fn(("edge_ones", total, int(seg.shape[0])),
                                 self._build_edge_ones)
@@ -415,9 +424,9 @@ class ShardedSparseExecutor(SparseExecutor):
                         factors: Sequence[jnp.ndarray]) -> jnp.ndarray:
         if self.n_ranks == 1 or self._force_local:
             return super()._reduce_by_code(code, ds, n, factors)
-        code_np = (np.zeros((n,), dtype=np.int32) if code is None
-                   else np.asarray(code))
-        code_p, w = _pad_to(code_np, self.n_ranks)
+        if code is None:
+            code = jnp.zeros((n,), dtype=jnp.int32)
+        code_p, w = _pad_rows(code, self.n_ranks)
         if not factors:
             fn = self._shard_fn(("reduce_ones", ds, int(code_p.shape[0])),
                                 self._build_reduce_ones)

@@ -196,13 +196,22 @@ class CountingEngine:
     def contract(self, point: LatticePoint,
                  keep: Optional[Sequence[CtVar]] = None) -> CtTable:
         """Positive ct-table straight from the data (counts as JOIN work),
-        inside a ``count.positive`` span of one table."""
+        inside a ``count.positive`` span of one table.  The span carries
+        the executor's hop counters: ``edges_resident``, hops whose edge
+        columns were already on the device, and ``edges_uploaded``, hops
+        that had to upload them."""
         tr = self.tracer
+        ex = self.executor
         with tr.span("count.positive") as sp:
             plan = self.plan(point, keep)
-            if tr.enabled:
-                sp.set(tables=1, hops=plan.hops)
-            return self.executor.positive(self.db, plan, self.stats)
+            if not tr.enabled:
+                return ex.positive(self.db, plan, self.stats)
+            sp.set(tables=1, hops=plan.hops)
+            resident, uploaded = ex.edges_resident, ex.edges_uploaded
+            tab = ex.positive(self.db, plan, self.stats)
+            sp.set(edges_resident=ex.edges_resident - resident,
+                   edges_uploaded=ex.edges_uploaded - uploaded)
+            return tab
 
     def hist(self, var: Var, keep: Tuple[CtVar, ...]) -> CtTable:
         """A variable's histogram over ``keep``, cached on the host in

@@ -27,6 +27,9 @@ executor's dtype (:mod:`repro.core.mobius`).
 
 from __future__ import annotations
 
+import functools
+import threading
+import weakref
 from contextlib import nullcontext
 from typing import List, Optional, Sequence, Tuple
 
@@ -90,6 +93,45 @@ def _finalise(flat: jnp.ndarray, mvars: Sequence[CtVar],
     return tab
 
 
+class _DeviceMirrors:
+    """Device copies of host columns, one per live host array.
+
+    A copy is keyed by the identity of the host array it copies and lives
+    no longer than that array: a weak reference drops it, so the small
+    arrays of a delta view do not pile up.  ``tag`` marks the copy of a
+    column that is written in place (entity attributes): a copy made
+    under another tag is replaced, never read.  Edge columns are never
+    written in place -- a write assigns new arrays -- so their copies
+    carry no tag and serve every store version that shares the array.
+    Only raw store columns are held here, never counts."""
+
+    def __init__(self):
+        # id(host) -> (weak ref to host, tag, device copy)
+        self._live: dict = {}
+
+    def __len__(self) -> int:
+        return len(self._live)
+
+    def get(self, host: np.ndarray, tag, upload
+            ) -> Tuple[jnp.ndarray, bool]:
+        """``(device copy, resident)``: the copy of ``host`` made under
+        ``tag``, uploaded through ``upload`` unless one is held."""
+        key = id(host)
+        hit = self._live.get(key)
+        if hit is not None and hit[0]() is host and hit[1] == tag:
+            return hit[2], True
+        dev = upload(host)
+        owner = weakref.ref(self)
+
+        def drop(ref, key=key):
+            live = owner()
+            if live is not None and live._live.get(key, (None,))[0] is ref:
+                del live._live[key]
+
+        self._live[key] = (weakref.ref(host, drop), tag, dev)
+        return dev, False
+
+
 class Executor:
     """Backend interface: evaluate plans against a database."""
 
@@ -102,15 +144,33 @@ class Executor:
         # request tracer for jit-dispatch spans (NULL_TRACER is free); a
         # real one is wired in by CountingService.set_tracer
         self.tracer = NULL_TRACER
+        # device copies of the store columns eager hops read, and the hops
+        # that found their edge columns there or had to upload them; the
+        # lock guards both, since tenant services share one executor
+        self._mirrors = _DeviceMirrors()
+        self.edges_resident = 0
+        self.edges_uploaded = 0
+        self._mirror_lock = threading.Lock()
 
-    def _stage(self, host: np.ndarray) -> jnp.ndarray:
+    def _stage(self, host: np.ndarray, copy=None) -> jnp.ndarray:
         """Upload one host array to the device, inside a ``host.stage``
         span carrying its bytes."""
         tr = self.tracer
         with tr.span("host.stage") as sp:
             if tr.enabled:
                 sp.set(nbytes=int(host.nbytes))
-            return jnp.asarray(host)
+            return jnp.asarray(host, copy=copy)
+
+    def _mirror(self, host, tag=None) -> Tuple[jnp.ndarray, bool]:
+        """``(device copy, resident)`` of a store column: staged on first
+        use, then kept while the host array lives (see
+        :class:`_DeviceMirrors`; ``tag`` for in-place columns).  The
+        copy never aliases the host array, which the CPU backend would
+        otherwise share and so keep alive."""
+        with self._mirror_lock:
+            return self._mirrors.get(
+                np.asarray(host), tag,
+                functools.partial(self._stage, copy=True))
 
     def local_mode(self):
         """Context for tiny side computations — the engine's delta count
@@ -982,11 +1042,83 @@ class _SparseMsg:
         self.dense, self.dvars = dense, dvars
 
 
-def _np_codes(cols: List[np.ndarray], cards: List[int]) -> np.ndarray:
-    code = np.zeros(len(cols[0]) if cols else 0, dtype=np.int64)
-    for col, card in zip(cols, cards):
-        code = code * card + col.astype(np.int64)
+@functools.partial(jax.jit, static_argnames=("cards",))
+def _mixed_radix(cols: Tuple[jnp.ndarray, ...],
+                 cards: Tuple[int, ...]) -> jnp.ndarray:
+    """Mixed-radix ``int32`` code of aligned value columns."""
+    code = cols[0].astype(jnp.int32)
+    for col, card in zip(cols[1:], cards[1:]):
+        code = code * card + col.astype(jnp.int32)
     return code
+
+
+def _hop_code_space(child_ds: int, cards: Tuple[int, ...],
+                    n_parent: int) -> Tuple[int, int]:
+    """``(ds, total)`` of a sparse hop: its code space, the child's
+    extended by the kept edge attributes, and its segment space, which
+    must fit ``int32``."""
+    ds = child_ds * int(np.prod(cards, dtype=np.int64))
+    total = n_parent * ds
+    if total > _INT32_LIMIT:
+        raise OverflowError(
+            f"sparse hop segment space {total} exceeds int32; use the "
+            f"dense executor or reduce kept axes")
+    return ds, total
+
+
+_ROW = 128              # codes per gathered row: one lane-wide vector
+_ROW_CHUNK = 1 << 16    # ids per step of a row gather: 32 MB of rows
+
+
+def _gathers_by_rows() -> bool:
+    """Should an eager hop gather its child codes by rows
+    (:func:`_take_by_rows`)?  On a TPU a lane-wide row per id comes about
+    three times faster than one element; other backends gather
+    elements."""
+    return jax.default_backend() == "tpu"
+
+
+def _take_by_rows(code: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``code[idx]`` for in-range ids: fetch each id's row of ``_ROW``
+    codes, then pick its lane with a compare and a sum.  Ids go in
+    steps of ``_ROW_CHUNK``, so the fetched rows stay at 32 MB."""
+    tab = jnp.pad(code, (0, -code.shape[0] % _ROW)).reshape(-1, _ROW)
+    lane = jax.lax.broadcasted_iota(idx.dtype, (1, _ROW), 1)
+
+    def take(ids):
+        rows = tab.at[ids // _ROW].get(mode="promise_in_bounds")
+        return jnp.sum(jnp.where(lane == (ids % _ROW)[:, None], rows, 0),
+                       axis=1, dtype=code.dtype)
+
+    n = idx.shape[0]
+    if n <= _ROW_CHUNK:
+        return take(idx)
+    steps = -(-n // _ROW_CHUNK)
+    ids = jnp.pad(idx, (0, steps * _ROW_CHUNK - n)).reshape(steps, -1)
+    return jax.lax.map(take, ids).reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("ds", "cards", "by_rows"))
+def _hop_segment_ids(code: Optional[jnp.ndarray], gather: jnp.ndarray,
+                     scatter: jnp.ndarray,
+                     edge_cols: Tuple[jnp.ndarray, ...], ds: int,
+                     cards: Tuple[int, ...],
+                     by_rows: bool = False) -> jnp.ndarray:
+    """Segment id of every edge of a sparse hop, in ``int32``:
+    ``scatter * ds + ((code[gather] * c1 + e1) * c2 + e2 ...)``, the
+    parent end of the edge times the hop's code space plus the child's
+    code extended with the kept edge attributes.  ``code`` is ``None``
+    when the child keeps no attribute; ``by_rows`` gathers it with
+    :func:`_take_by_rows`."""
+    if code is None:
+        ecode = jnp.zeros(gather.shape, dtype=jnp.int32)
+    elif by_rows:
+        ecode = _take_by_rows(code, gather)
+    else:
+        ecode = code[gather]
+    for col, card in zip(edge_cols, cards):
+        ecode = ecode * card + col.astype(jnp.int32)
+    return scatter.astype(jnp.int32) * ds + ecode
 
 
 def _kr_segment_sum(code, mats: Sequence[jnp.ndarray], ds: int,
@@ -1023,56 +1155,53 @@ class SparseExecutor(Executor):
     name = "sparse"
 
     def _entity_code(self, db: RelationalDB, fs: FactorSpec
-                     ) -> Tuple[Optional[np.ndarray], int]:
-        """Mixed-radix host-side code per entity.  Kept as numpy: codes are
-        consumed by host index arithmetic in ``_hop``; only the final
-        segment-id array ever moves to the device."""
+                     ) -> Tuple[Optional[jnp.ndarray], int]:
+        """Mixed-radix device code per entity, from device copies of the
+        entity's attribute columns made at the store's version.  The
+        table's write count joins the tag: stores that share the table
+        (shards, delta views) may stand at one version number with
+        different writes behind it."""
         if not fs.attrs:
             return None, 1
         tab = db.entities[fs.var.etype]
-        cols = [np.asarray(tab.attrs[cv.owner[1]]) for cv in fs.attrs]
-        code = _np_codes(cols, [cv.card for cv in fs.attrs])
-        return code.astype(np.int32), fs.card
+        tag = (db.version, tab.writes)
+        cols = tuple(self._mirror(tab.attrs[cv.owner[1]], tag)[0]
+                     for cv in fs.attrs)
+        return _mixed_radix(cols, tuple(cv.card for cv in fs.attrs)), fs.card
 
     def _hop(self, db: RelationalDB, hop: HopSpec, msg: _SparseMsg,
              stats: Optional[CostStats]
              ) -> Tuple[jnp.ndarray, Tuple[CtVar, ...]]:
         """Push a child message through one relationship.  Scalar-coded axes
-        travel as index arithmetic inside the segment ids; only genuinely
-        dense axes (from deeper aggregations) are carried as row vectors."""
+        travel as index arithmetic inside the segment ids, computed on the
+        device from the relationship's device-resident edge columns; only
+        genuinely dense axes (from deeper aggregations) are carried as row
+        vectors."""
         rt, gather_idx, scatter_idx, n_parent = _hop_indices(
             db, hop.atom, hop.child, hop.parent)
-        gather_np = np.asarray(gather_idx)
-        n_edges = int(gather_np.shape[0])
-
-        # per-edge scalar code: child code gathered at the child end of the
-        # edge, extended with this relationship's kept edge attributes
-        ds = msg.ds
-        if msg.code is not None:
-            ecode = msg.code[gather_np].astype(np.int64)
-        else:
-            ecode = np.zeros(n_edges, dtype=np.int64)
-        svars = tuple(msg.svars)
-        for cv in hop.edge_attrs:
-            ecode = ecode * cv.card + np.asarray(
-                rt.attrs[cv.owner[1]]).astype(np.int64)
-            ds *= cv.card
-            svars = svars + (cv,)
-
-        total = n_parent * ds
-        if total > _INT32_LIMIT:
-            raise OverflowError(
-                f"sparse hop segment space {total} exceeds int32; use the "
-                f"dense executor or reduce kept axes")
-        seg_np = (np.asarray(scatter_idx).astype(np.int64) * ds
-                  + ecode).astype(np.int32)
+        cards = tuple(cv.card for cv in hop.edge_attrs)
+        ds, total = _hop_code_space(msg.ds, cards, n_parent)
+        mirrored = [self._mirror(a) for a in (
+            gather_idx, scatter_idx,
+            *(rt.attrs[cv.owner[1]] for cv in hop.edge_attrs))]
+        with self._mirror_lock:
+            if all(resident for _, resident in mirrored):
+                self.edges_resident += 1
+            else:
+                self.edges_uploaded += 1
+        gather, scatter, *edge_cols = (dev for dev, _ in mirrored)
+        n_edges = int(gather.shape[0])
+        svars = tuple(msg.svars) + tuple(hop.edge_attrs)
+        seg = _hop_segment_ids(msg.code, gather, scatter, tuple(edge_cols),
+                               ds=ds, cards=cards,
+                               by_rows=_gathers_by_rows())
         if msg.dense is None:
-            flat = self._edge_segment_sum(seg_np, None, total)
+            flat = self._edge_segment_sum(seg, None, total)
             out = flat.reshape(n_parent, ds)
             out_vars = svars
         else:
-            rows = msg.dense[self._stage(gather_np)]       # (edges, Dd)
-            agg = self._edge_segment_sum(seg_np, rows, total)
+            rows = msg.dense[gather]                       # (edges, Dd)
+            agg = self._edge_segment_sum(seg, rows, total)
             out = agg.reshape(n_parent, ds * msg.dense.shape[1])
             out_vars = svars + tuple(msg.dvars)
         if stats is not None:
@@ -1080,16 +1209,16 @@ class SparseExecutor(Executor):
             stats.rows_scanned += n_edges
         return out, out_vars
 
-    def _edge_segment_sum(self, seg_np: np.ndarray,
+    def _edge_segment_sum(self, seg: jnp.ndarray,
                           rows: Optional[jnp.ndarray],
                           total: int) -> jnp.ndarray:
         """Device step of one sparse hop: scatter-add per-edge contributions
-        into the flattened ``(parent, code)`` segment space.  ``rows`` is
-        ``None`` for a leaf hop (each edge contributes 1) or the gathered
-        dense block ``(edges, Dd)``.  The single-device base runs one
-        ``jax.ops.segment_sum``; :class:`~repro.core.distributed
-        .ShardedSparseExecutor` overrides this with an edge-sharded
-        ``shard_map`` + ``psum``.
+        into the flattened ``(parent, code)`` segment space at the device
+        segment ids ``seg``.  ``rows`` is ``None`` for a leaf hop (each
+        edge contributes 1) or the gathered dense block ``(edges, Dd)``.
+        The single-device base runs one ``jax.ops.segment_sum``;
+        :class:`~repro.core.distributed.ShardedSparseExecutor` overrides
+        this with an edge-sharded ``shard_map`` + ``psum``.
 
         Backend routing: when :func:`repro.kernels.ops
         .segsum_kernel_enabled` says so (accelerator present, or
@@ -1098,19 +1227,18 @@ class SparseExecutor(Executor):
         the Pallas kernel (:mod:`repro.kernels.segsum_kernel`) with
         ``interpret`` resolved by the same backend probe — Mosaic on
         TPU, Triton on GPU, the interpreter on CPU."""
-        seg = self._stage(seg_np)
         if _segsum_kernel_enabled(total):
             from ..kernels import ops as kernel_ops
             if rows is None:
                 out = kernel_ops.ones_segment_sum(
-                    seg, jnp.ones((seg_np.shape[0],), dtype=jnp.float32),
+                    seg, jnp.ones((seg.shape[0],), dtype=jnp.float32),
                     total)
             else:
                 out = kernel_ops.edge_segment_sum(seg, rows, total)
             return out.astype(self.dtype)
         if rows is None:
             return jax.ops.segment_sum(
-                jnp.ones((seg_np.shape[0],), dtype=self.dtype), seg,
+                jnp.ones((seg.shape[0],), dtype=self.dtype), seg,
                 num_segments=total)
         return jax.ops.segment_sum(rows, seg, num_segments=total)
 
@@ -1159,8 +1287,6 @@ class SparseExecutor(Executor):
         expansion would not fit)."""
         if code is None:
             code = jnp.zeros((n,), dtype=jnp.int32)
-        elif isinstance(code, np.ndarray):
-            code = self._stage(code)
         if not factors:
             return self._ones_segment_sum(code, ds)
         if len(factors) == 1:
@@ -1212,36 +1338,29 @@ class SparseExecutor(Executor):
     # -- traced batched evaluation ------------------------------------------
     def _flat_from_arrays(self, db: RelationalDB, plan: ContractionPlan,
                           cur: _ArrayCursor) -> jnp.ndarray:
-        """Device-side mirror of ``_entity_code``/``_hop``/``_node_message``
-        + ``root_reduce``: the host numpy code arithmetic becomes jnp int32
-        arithmetic so the whole evaluation traces under ``vmap``.  The
-        int32 segment-space guard is static, so it still raises at trace
-        time."""
+        """Traced mirror of ``_entity_code``/``_hop``/``_node_message``
+        + ``root_reduce``, reading an input pack, so the whole evaluation
+        traces under ``vmap``; it runs the eager path's code and segment-id
+        programs inside its trace, gathering codes element by element
+        (by rows, a batch of plans would fetch a batch of rows per step).
+        The int32 segment-space guard is static, so it still raises at
+        trace time."""
         def entity_code(fs: FactorSpec):
             if not fs.attrs:
                 return None, 1
-            code = None
-            for cv in fs.attrs:
-                col = cur.take().astype(jnp.int32)
-                code = col if code is None else code * cv.card + col
-            return code, fs.card
+            cols = tuple(cur.take() for _ in fs.attrs)
+            return (_mixed_radix(cols, tuple(cv.card for cv in fs.attrs)),
+                    fs.card)
 
         def hop_from(hop: HopSpec, msg: _SparseMsg) -> jnp.ndarray:
             g, s = cur.take(), cur.take()
             n_parent = db.entities[hop.parent.etype].size
             n_edges = int(g.shape[0])
-            ds = msg.ds
-            ecode = (msg.code[g] if msg.code is not None
-                     else jnp.zeros((n_edges,), dtype=jnp.int32))
-            for cv in hop.edge_attrs:
-                ecode = ecode * cv.card + cur.take().astype(jnp.int32)
-                ds *= cv.card
-            total = n_parent * ds
-            if total > _INT32_LIMIT:
-                raise OverflowError(
-                    f"sparse hop segment space {total} exceeds int32; use "
-                    f"the dense executor or reduce kept axes")
-            seg = s.astype(jnp.int32) * ds + ecode
+            cards = tuple(cv.card for cv in hop.edge_attrs)
+            ds, total = _hop_code_space(msg.ds, cards, n_parent)
+            seg = _hop_segment_ids(msg.code, g, s,
+                                   tuple(cur.take() for _ in cards),
+                                   ds=ds, cards=cards)
             if msg.dense is None:
                 flat = jax.ops.segment_sum(
                     jnp.ones((n_edges,), dtype=self.dtype), seg,

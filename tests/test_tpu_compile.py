@@ -4,7 +4,9 @@ Each case compiles one kernel, at the shapes the counting path gives it,
 for a v5e chip that is described rather than attached: the TPU compiler
 refuses here what it would refuse on the chip (block tiling, VMEM, ops
 Mosaic cannot lower), and the compiled program must hold the kernel as a
-``tpu_custom_call``.  Nothing runs.
+``tpu_custom_call``.  The sparse hop's segment-id program, which gathers
+its child codes by rows on a TPU, compiles there within the memory its
+steps allow.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each test worker imports
@@ -78,3 +80,18 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     fn = jax.jit(functools.partial(kernel, interpret=False, **kwargs))
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_a_hop_gathers_codes_by_rows_in_bounded_steps(one_chip,
+                                                      no_persistent_cache):
+    """A VisualGenome hop: 1.9 M edge ids into 200 k entity codes, with one
+    edge attribute.  Fetched all at once, the rows would take 973 MB."""
+    from repro.core.executors import _ROW_CHUNK, _hop_segment_ids
+
+    edges = jax.ShapeDtypeStruct((1_900_000,), I32, sharding=one_chip)
+    code = jax.ShapeDtypeStruct((200_000,), I32, sharding=one_chip)
+    compiled = _hop_segment_ids.lower(
+        code, edges, edges, (edges,), ds=72, cards=(3,),
+        by_rows=True).compile()
+    rows = _ROW_CHUNK * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * rows
