@@ -48,7 +48,7 @@ ROOT = Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 DEBUG_OVERRIDES = ("REPRO_PALLAS_INTERPRET", "REPRO_SEGSUM_PALLAS")
-VG_CACHE_BUDGET = 64 << 20     # vg1.0cache64MB in benchmarks/perf_smoke.py
+VG_CACHE_BUDGET = 64 << 20     # the cache budget of bench/configs/vg-full.json
 COMPLETE_RTOL = 1e-6           # complete cells reach 4e10: f32 rounding
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

@@ -1,6 +1,4 @@
-"""Serve counting queries to concurrent clients: a CountingService flood
-demo (the counting-engine analogue of ``examples/serve_batched.py`` for
-models).
+"""Serve counting queries to concurrent clients: a CountingService flood demo.
 
 Several client threads flood one :class:`~repro.serve.service
 .CountingService` with positive-count queries over a schema whose

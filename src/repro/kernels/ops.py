@@ -134,18 +134,3 @@ def _bdeu(nijk: jnp.ndarray, ess: float, interpret: bool) -> jnp.ndarray:
 def bdeu(nijk: jnp.ndarray, ess: float = 1.0,
          interpret: Optional[bool] = None) -> jnp.ndarray:
     return _bdeu(nijk, ess=ess, interpret=_resolve(interpret))
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
-def _flash_attention(q, k, v, causal: bool, block_q: int, block_k: int,
-                     interpret: bool):
-    from .attention_kernel import flash_attention_pallas
-    return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=interpret)
-
-
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, interpret: Optional[bool] = None):
-    return _flash_attention(q, k, v, causal=causal, block_q=block_q,
-                            block_k=block_k, interpret=_resolve(interpret))

@@ -35,17 +35,3 @@ def bdeu_ref(nijk: jnp.ndarray, ess: float, q: int, r: int) -> jnp.ndarray:
     per_j = (gammaln(a_j) - gammaln(nij + a_j)
              + jnp.sum(gammaln(nijk + a_jk) - gammaln(a_jk), axis=1))
     return jnp.sum(per_j)
-
-
-def flash_attention_ref(q, k, v, causal: bool = True):
-    """Oracle for the flash-attention kernel: q/k/v [B,S,H,hd], H already
-    broadcast (GQA groups expanded by the caller)."""
-    b, sq, h, hd = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * hd ** -0.5
-    if causal:
-        m = jnp.tril(jnp.ones((sq, k.shape[1]), bool))
-        s = jnp.where(m[None, None], s, -1e30)
-    w = jax.nn.softmax(s, -1)
-    return jnp.einsum("bhqk,bkhd->bqhd", w,
-                      v.astype(jnp.float32)).astype(q.dtype)

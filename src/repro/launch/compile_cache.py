@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache, placed from outside.
 
-Entry points (``chip_smoke.py``, :func:`repro.launch.discover.main`,
-``benchmarks/run.py``) call :func:`enable_compile_cache` once at start-up;
-importing :mod:`repro` never does, so compiles for a described TPU in the
-tests do not write cache entries that no later run could read.
+Entry points (``chip_smoke.py`` and :func:`repro.launch.discover.main`)
+call :func:`enable_compile_cache` once at start-up; importing
+:mod:`repro` never does, so compiles for a described TPU in the tests do
+not write cache entries that no later run could read.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets no directory.  Otherwise the cache lives in ``.jax_cache/`` at

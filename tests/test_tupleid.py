@@ -4,10 +4,10 @@ never touch edge tables during search."""
 
 import numpy as np
 
-from benchmarks.bench_counting import family_workload
 from repro.core.database import paper_benchmark_db
 from repro.core.strategies import make_strategy
 from repro.core.variables import build_lattice
+from tests._workloads import family_workload
 
 
 def test_tupleid_matches_hybrid():
