@@ -404,6 +404,15 @@ class ShardedSparseExecutor(SparseExecutor):
                                  out_specs=P(None, None), check_vma=False))
 
     # -- device primitives, sharded -----------------------------------------
+    def _blocked_layout(self, rt, gather_idx, scatter_idx, n_parent: int,
+                        ds: int):
+        # a blocked layout is one device's; a sharded hop scatters its
+        # flat segment ids over the mesh
+        if self.n_ranks == 1 or self._force_local:
+            return super()._blocked_layout(rt, gather_idx, scatter_idx,
+                                           n_parent, ds)
+        return None, False
+
     def _edge_segment_sum(self, seg: jax.Array,
                           rows: Optional[jnp.ndarray],
                           total: int) -> jnp.ndarray:

@@ -72,6 +72,10 @@ from .mobius import complete_ct_delta_many
 from .plan import ContractionPlan, compile_plan_cached
 from .variables import Atom, CtVar, LatticePoint, Var, attr_var, edge_var
 
+# the executor's hop counters that ``contract`` puts on its spans
+_HOP_COUNTERS = ("edges_resident", "edges_uploaded", "blocked_hops",
+                 "scattered_hops")
+
 
 def _attr_tags(keep) -> Set[Tuple]:
     """``("attr", etype, name)`` tags for the entity-attr axes of a keep
@@ -198,8 +202,10 @@ class CountingEngine:
         """Positive ct-table straight from the data (counts as JOIN work),
         inside a ``count.positive`` span of one table.  The span carries
         the executor's hop counters: ``edges_resident``, hops whose edge
-        columns were already on the device, and ``edges_uploaded``, hops
-        that had to upload them."""
+        columns were already on the device, ``edges_uploaded``, hops
+        that had to upload them, ``blocked_hops``, hops that ran the
+        blocked kernel over a parent-sorted layout, and
+        ``scattered_hops``, hops that scattered flat segment ids."""
         tr = self.tracer
         ex = self.executor
         with tr.span("count.positive") as sp:
@@ -207,10 +213,10 @@ class CountingEngine:
             if not tr.enabled:
                 return ex.positive(self.db, plan, self.stats)
             sp.set(tables=1, hops=plan.hops)
-            resident, uploaded = ex.edges_resident, ex.edges_uploaded
+            before = [getattr(ex, c) for c in _HOP_COUNTERS]
             tab = ex.positive(self.db, plan, self.stats)
-            sp.set(edges_resident=ex.edges_resident - resident,
-                   edges_uploaded=ex.edges_uploaded - uploaded)
+            sp.set(**{c: getattr(ex, c) - b
+                      for c, b in zip(_HOP_COUNTERS, before)})
             return tab
 
     def hist(self, var: Var, keep: Tuple[CtVar, ...]) -> CtTable:

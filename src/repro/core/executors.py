@@ -31,7 +31,7 @@ import functools
 import threading
 import weakref
 from contextlib import nullcontext
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,32 +103,38 @@ class _DeviceMirrors:
     under another tag is replaced, never read.  Edge columns are never
     written in place -- a write assigns new arrays -- so their copies
     carry no tag and serve every store version that shares the array.
-    Only raw store columns are held here, never counts."""
+    A value made from several columns (a relationship's blocked layout)
+    is keyed by all of them, and dies with the first to go.  Only raw
+    store columns, and layouts of them, are held here, never counts."""
 
     def __init__(self):
-        # id(host) -> (weak ref to host, tag, device copy)
+        # ids of the hosts -> (weak refs to the hosts, tag, device value)
         self._live: dict = {}
 
     def __len__(self) -> int:
         return len(self._live)
 
-    def get(self, host: np.ndarray, tag, upload
-            ) -> Tuple[jnp.ndarray, bool]:
-        """``(device copy, resident)``: the copy of ``host`` made under
-        ``tag``, uploaded through ``upload`` unless one is held."""
-        key = id(host)
+    def get(self, host, tag, upload) -> Tuple[object, bool]:
+        """``(device value, resident)``: the value made from ``host``, one
+        array or a tuple of them, under ``tag``, made through ``upload``
+        unless one is held."""
+        hosts = host if isinstance(host, tuple) else (host,)
+        key = tuple(map(id, hosts))
         hit = self._live.get(key)
-        if hit is not None and hit[0]() is host and hit[1] == tag:
+        if (hit is not None and hit[1] == tag
+                and all(r() is h for r, h in zip(hit[0], hosts))):
             return hit[2], True
         dev = upload(host)
         owner = weakref.ref(self)
 
         def drop(ref, key=key):
             live = owner()
-            if live is not None and live._live.get(key, (None,))[0] is ref:
+            held = live._live.get(key) if live is not None else None
+            if held is not None and any(r is ref for r in held[0]):
                 del live._live[key]
 
-        self._live[key] = (weakref.ref(host, drop), tag, dev)
+        self._live[key] = (tuple(weakref.ref(h, drop) for h in hosts),
+                           tag, dev)
         return dev, False
 
 
@@ -150,6 +156,11 @@ class Executor:
         self._mirrors = _DeviceMirrors()
         self.edges_resident = 0
         self.edges_uploaded = 0
+        # blocked layouts of the relationships leaf hops read, and the hops
+        # that ran the blocked kernel or scattered (same lock)
+        self._layouts = _DeviceMirrors()
+        self.blocked_hops = 0
+        self.scattered_hops = 0
         self._mirror_lock = threading.Lock()
 
     def _stage(self, host: np.ndarray, copy=None) -> jnp.ndarray:
@@ -1098,18 +1109,13 @@ def _take_by_rows(code: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.map(take, ids).reshape(-1)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("ds", "cards", "by_rows"))
-def _hop_segment_ids(code: Optional[jnp.ndarray], gather: jnp.ndarray,
-                     scatter: jnp.ndarray,
-                     edge_cols: Tuple[jnp.ndarray, ...], ds: int,
-                     cards: Tuple[int, ...],
-                     by_rows: bool = False) -> jnp.ndarray:
-    """Segment id of every edge of a sparse hop, in ``int32``:
-    ``scatter * ds + ((code[gather] * c1 + e1) * c2 + e2 ...)``, the
-    parent end of the edge times the hop's code space plus the child's
-    code extended with the kept edge attributes.  ``code`` is ``None``
-    when the child keeps no attribute; ``by_rows`` gathers it with
-    :func:`_take_by_rows`."""
+def _edge_codes(code: Optional[jnp.ndarray], gather: jnp.ndarray,
+                edge_cols: Tuple[jnp.ndarray, ...], cards: Tuple[int, ...],
+                by_rows: bool) -> jnp.ndarray:
+    """The child's code at every edge extended with the kept edge
+    attributes, ``(code[gather] * c1 + e1) * c2 + e2 ...``, in ``int32``.
+    ``code`` is ``None`` when the child keeps no attribute; ``by_rows``
+    gathers it with :func:`_take_by_rows`."""
     if code is None:
         ecode = jnp.zeros(gather.shape, dtype=jnp.int32)
     elif by_rows:
@@ -1118,7 +1124,85 @@ def _hop_segment_ids(code: Optional[jnp.ndarray], gather: jnp.ndarray,
         ecode = code[gather]
     for col, card in zip(edge_cols, cards):
         ecode = ecode * card + col.astype(jnp.int32)
-    return scatter.astype(jnp.int32) * ds + ecode
+    return ecode
+
+
+@functools.partial(jax.jit, static_argnames=("ds", "cards", "by_rows"))
+def _hop_segment_ids(code: Optional[jnp.ndarray], gather: jnp.ndarray,
+                     scatter: jnp.ndarray,
+                     edge_cols: Tuple[jnp.ndarray, ...], ds: int,
+                     cards: Tuple[int, ...],
+                     by_rows: bool = False) -> jnp.ndarray:
+    """Segment id of every edge of a sparse hop, in ``int32``: the parent
+    end of the edge times the hop's code space ``ds`` plus the edge's
+    code (:func:`_edge_codes`)."""
+    return (scatter.astype(jnp.int32) * ds
+            + _edge_codes(code, gather, edge_cols, cards, by_rows))
+
+
+@functools.partial(jax.jit, static_argnames=("cards", "by_rows"))
+def _hop_slot_codes(code: Optional[jnp.ndarray], child: jnp.ndarray,
+                    edge_cols: Tuple[jnp.ndarray, ...],
+                    cards: Tuple[int, ...],
+                    by_rows: bool = False) -> jnp.ndarray:
+    """The code of every slot of a blocked layout (:class:`_BlockedLayout`),
+    ``(T, S)``: :func:`_edge_codes` of the slots' children and edge
+    attributes.  The parent stays out; the slot's row and offset give
+    it."""
+    flat = _edge_codes(code, child.reshape(-1),
+                       tuple(c.reshape(-1) for c in edge_cols), cards,
+                       by_rows)
+    return flat.reshape(child.shape)
+
+
+# slots a tile of a blocked layout holds, rounded up to this so that the
+# relationships of one deployment share one compiled kernel
+_SLOT_ROUND = 512
+# slots per edge past which a blocked layout is not built: a hub parent
+# whose tile sets the slots of every tile keeps XLA scatter
+_MAX_SLOTS_PER_EDGE = 1.5
+
+
+def _slot_sources(parent: np.ndarray, n_parent: int) -> Optional[np.ndarray]:
+    """The edge each slot of a blocked layout over ``parent`` holds,
+    ``(T, S)``, and ``len(parent)`` on a padding slot; ``None`` where
+    the layout would hold more than ``_MAX_SLOTS_PER_EDGE`` slots an
+    edge.  Row ``t`` takes the edges of parents ``[t * TILE_PARENTS,
+    (t + 1) * TILE_PARENTS)``, in their order in ``parent``."""
+    from ..kernels.segsum_kernel import TILE_PARENTS, TILE_ROWS
+    n = int(parent.shape[0])
+    if n == 0:
+        return None
+    tiles = -(-n_parent // TILE_PARENTS)
+    if tiles > TILE_ROWS:
+        tiles = -(-tiles // TILE_ROWS) * TILE_ROWS
+    tile = parent // TILE_PARENTS
+    counts = np.bincount(tile, minlength=tiles)
+    slots = -(-int(counts.max()) // _SLOT_ROUND) * _SLOT_ROUND
+    if tiles * slots > _MAX_SLOTS_PER_EDGE * n:
+        return None
+    # a stable sort of 16-bit keys is a radix sort
+    key = tile.astype(np.int16 if tiles <= np.iinfo(np.int16).max
+                      else np.int32)
+    order = np.argsort(key, kind="stable")
+    first = np.arange(tiles, dtype=np.int64) * slots - (np.cumsum(counts)
+                                                        - counts)
+    src = np.full(tiles * slots, n, dtype=np.int64)
+    src[np.repeat(first, counts) + np.arange(n)] = order
+    return src.reshape(tiles, slots)
+
+
+class _BlockedLayout(NamedTuple):
+    """A relationship's edges sorted by their parent end into tiles of
+    ``TILE_PARENTS`` parents, a fixed number of slots a tile, on the
+    device: per slot the parent's offset in its tile, the child, each
+    edge attribute by name and a 0/1 weight, 0 on padding slots.  These
+    are the operands of :func:`repro.kernels.ops.blocked_ones_segment_sum`."""
+
+    parent: jnp.ndarray
+    child: jnp.ndarray
+    attrs: dict
+    weight: jnp.ndarray
 
 
 def _kr_segment_sum(code, mats: Sequence[jnp.ndarray], ds: int,
@@ -1176,38 +1260,96 @@ class SparseExecutor(Executor):
         travel as index arithmetic inside the segment ids, computed on the
         device from the relationship's device-resident edge columns; only
         genuinely dense axes (from deeper aggregations) are carried as row
-        vectors."""
+        vectors.  A leaf hop that :meth:`_blocked_layout` grants reads the
+        relationship's blocked layout in place of its edge columns."""
         rt, gather_idx, scatter_idx, n_parent = _hop_indices(
             db, hop.atom, hop.child, hop.parent)
         cards = tuple(cv.card for cv in hop.edge_attrs)
         ds, total = _hop_code_space(msg.ds, cards, n_parent)
+        svars = tuple(msg.svars) + tuple(hop.edge_attrs)
+        n_edges = len(gather_idx)
+        if stats is not None:
+            stats.joins += 1
+            stats.rows_scanned += n_edges
+        layout = None
+        if msg.dense is None:
+            layout, resident = self._blocked_layout(
+                rt, gather_idx, scatter_idx, n_parent, ds)
+        if layout is not None:
+            self._count_hop(resident, blocked=True)
+            from ..kernels import ops as kernel_ops
+            codes = _hop_slot_codes(
+                msg.code, layout.child,
+                tuple(layout.attrs[cv.owner[1]] for cv in hop.edge_attrs),
+                cards=cards, by_rows=_gathers_by_rows())
+            out = kernel_ops.blocked_ones_segment_sum(
+                layout.parent, codes, layout.weight, n_parent, ds)
+            return out.astype(self.dtype), svars
         mirrored = [self._mirror(a) for a in (
             gather_idx, scatter_idx,
             *(rt.attrs[cv.owner[1]] for cv in hop.edge_attrs))]
-        with self._mirror_lock:
-            if all(resident for _, resident in mirrored):
-                self.edges_resident += 1
-            else:
-                self.edges_uploaded += 1
+        self._count_hop(all(resident for _, resident in mirrored),
+                        blocked=False)
         gather, scatter, *edge_cols = (dev for dev, _ in mirrored)
-        n_edges = int(gather.shape[0])
-        svars = tuple(msg.svars) + tuple(hop.edge_attrs)
         seg = _hop_segment_ids(msg.code, gather, scatter, tuple(edge_cols),
                                ds=ds, cards=cards,
                                by_rows=_gathers_by_rows())
         if msg.dense is None:
             flat = self._edge_segment_sum(seg, None, total)
-            out = flat.reshape(n_parent, ds)
-            out_vars = svars
-        else:
-            rows = msg.dense[gather]                       # (edges, Dd)
-            agg = self._edge_segment_sum(seg, rows, total)
-            out = agg.reshape(n_parent, ds * msg.dense.shape[1])
-            out_vars = svars + tuple(msg.dvars)
-        if stats is not None:
-            stats.joins += 1
-            stats.rows_scanned += n_edges
-        return out, out_vars
+            return flat.reshape(n_parent, ds), svars
+        rows = msg.dense[gather]                           # (edges, Dd)
+        agg = self._edge_segment_sum(seg, rows, total)
+        return (agg.reshape(n_parent, ds * msg.dense.shape[1]),
+                svars + tuple(msg.dvars))
+
+    def _count_hop(self, resident: bool, blocked: bool) -> None:
+        """Count one hop in each pair of hop counters."""
+        with self._mirror_lock:
+            if resident:
+                self.edges_resident += 1
+            else:
+                self.edges_uploaded += 1
+            if blocked:
+                self.blocked_hops += 1
+            else:
+                self.scattered_hops += 1
+
+    def _blocked_layout(self, rt, gather_idx, scatter_idx, n_parent: int,
+                        ds: int) -> Tuple[Optional[_BlockedLayout], bool]:
+        """``(layout, resident)`` for a leaf hop that runs the blocked
+        kernel, ``(None, False)`` for one that scatters.  The blocked
+        kernel runs where :func:`repro.kernels.ops.blocked_segsum_enabled`
+        allows the hop's edges and codes, and the relationship's padded
+        layout holds at most ``_MAX_SLOTS_PER_EDGE`` slots an edge.  The
+        layout is built on first use and kept, like an edge column's
+        device copy, while the relationship's arrays live."""
+        from ..kernels import ops as kernel_ops
+        if not kernel_ops.blocked_segsum_enabled(len(gather_idx), ds):
+            return None, False
+        names = tuple(sorted(rt.attrs))
+        hosts = tuple(np.asarray(a) for a in (
+            scatter_idx, gather_idx, *(rt.attrs[name] for name in names)))
+        with self._mirror_lock:
+            return self._layouts.get(hosts, n_parent, functools.partial(
+                self._build_layout, names=names, n_parent=n_parent))
+
+    def _build_layout(self, hosts: Tuple[np.ndarray, ...],
+                      names: Tuple[str, ...],
+                      n_parent: int) -> Optional[_BlockedLayout]:
+        from ..kernels.segsum_kernel import TILE_PARENTS
+        parent, child, *attrs = hosts
+        src = _slot_sources(parent, n_parent)
+        if src is None:
+            return None
+        # a padding slot repeats the last edge, at weight 0
+        idx = np.minimum(src, len(parent) - 1)
+        base = np.arange(src.shape[0], dtype=np.int64)[:, None] * TILE_PARENTS
+        offset = (np.take(parent, idx) - base).astype(np.int32)
+        return _BlockedLayout(
+            self._stage(offset), self._stage(np.take(child, idx)),
+            {name: self._stage(np.take(col, idx))
+             for name, col in zip(names, attrs)},
+            self._stage((src < len(parent)).astype(np.float32)))
 
     def _edge_segment_sum(self, seg: jnp.ndarray,
                           rows: Optional[jnp.ndarray],

@@ -23,6 +23,13 @@ spill into the padded tail rows is sliced away on return.
 Grid layout: segments on the outer (parallel) grid dimension, edges on the
 innermost (sequential) dimension with ``+=`` accumulation, so each output
 tile stays resident in VMEM while the edge stream passes through.
+
+That sweep costs O(edges x segments), so it is capped to small segment
+spaces.  A leaf hop over a large parent axis instead takes
+:func:`blocked_segment_sum_pallas`: its edges come sorted by parent into
+tiles of ``TILE_PARENTS`` parents, each tile a fixed slab of slots, so a
+grid step contracts one slab against one tile of parents and the cost is
+O(slots x (codes + TILE_PARENTS)).
 """
 
 from __future__ import annotations
@@ -131,3 +138,57 @@ def segment_sum_ones_pallas(seg: jnp.ndarray, weights: jnp.ndarray,
         interpret=interpret,
     )(seg_p, w_p)
     return out[0, :num_segments]
+
+
+# parents per tile of the blocked layout: the lane width of a one-hot
+# tile and of each output block
+TILE_PARENTS = 256
+# parent tiles per grid step: an input block's rows, one sublane tile
+TILE_ROWS = 8
+
+
+def _blocked_kernel(parent_ref, code_ref, w_ref, o_ref, *, num_codes: int):
+    rows, slots = parent_ref.shape
+    codes = jax.lax.broadcasted_iota(jnp.int32, (num_codes, slots), 0)
+    parents = jax.lax.broadcasted_iota(jnp.int32, (TILE_PARENTS, slots), 0)
+    for r in range(rows):
+        # both one-hots are 0/1, exact in bfloat16; the MXU sums in f32
+        by_code = jnp.where(code_ref[r:r + 1, :] == codes,
+                            w_ref[r:r + 1, :], 0.0).astype(jnp.bfloat16)
+        by_parent = (parent_ref[r:r + 1, :] == parents).astype(jnp.bfloat16)
+        o_ref[:, r * TILE_PARENTS:(r + 1) * TILE_PARENTS] = \
+            jax.lax.dot_general(by_code, by_parent,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+
+
+def blocked_segment_sum_pallas(parent: jnp.ndarray, code: jnp.ndarray,
+                               weights: jnp.ndarray, num_codes: int, *,
+                               interpret: bool = True) -> jnp.ndarray:
+    """``out[t * TILE_PARENTS + parent[t, s], code[t, s]] += weights[t, s]``
+    over a parent-sorted slot layout: row ``t`` of the ``(T, S)`` inputs
+    holds the edges of parents ``[t * TILE_PARENTS, (t + 1) *
+    TILE_PARENTS)``, ``parent`` their parent's offset in that tile and
+    ``code`` their code, padding slots with weight 0.  ``T`` is below or a
+    multiple of ``TILE_ROWS``, the tiles of one grid step, and ``S`` a
+    multiple of 128.  Returns ``(T * TILE_PARENTS, num_codes)``.
+
+    Each tile is ``onehot(code)^T @ onehot(parent)`` on the MXU, in
+    bfloat16 with float32 sums: exact while no cell passes 2**24."""
+    tiles, slots = parent.shape
+    rows = min(tiles, TILE_ROWS)
+    if tiles % rows:
+        raise ValueError(f"{tiles} tiles is no multiple of {TILE_ROWS}")
+    block = pl.BlockSpec((rows, slots), lambda t: (t, 0))
+    out = pl.pallas_call(
+        functools.partial(_blocked_kernel, num_codes=num_codes),
+        grid=(tiles // rows,),
+        in_specs=[block, block, block],
+        out_specs=pl.BlockSpec((num_codes, rows * TILE_PARENTS),
+                               lambda t: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((num_codes, tiles * TILE_PARENTS),
+                                       jnp.float32),
+        interpret=interpret,
+    )(parent.astype(jnp.int32), code.astype(jnp.int32),
+      weights.astype(jnp.float32))
+    return out.T

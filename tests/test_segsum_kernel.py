@@ -15,7 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, segsum_kernel
 
 
 # ------------------------------------------------------------ segment-sum ---
@@ -68,6 +68,63 @@ def test_segsum_kernel_random_shapes(seed):
             err_msg=f"seed={seed} n={n} d={d} p={p}")
 
 
+# ------------------------------------------------ blocked (parent-sorted) ---
+def _blocked_layout(rng, tiles, slots, ds):
+    """A random parent-sorted slot layout: parents with no edge, a tile
+    filled to exactly ``slots``, padding slots holding junk at weight 0,
+    and a parent of degree 300 in tile 0, past bfloat16's exact 256."""
+    tp = segsum_kernel.TILE_PARENTS
+    parent = rng.integers(0, tp, (tiles, slots)).astype(np.int32)
+    code = rng.integers(0, ds, (tiles, slots)).astype(np.int32)
+    weight = np.zeros((tiles, slots), np.float32)
+    for t in range(tiles):
+        fill = slots if t == tiles - 1 else int(rng.integers(320, slots))
+        deg = np.bincount(rng.integers(0, tp // 2, fill), minlength=tp)
+        if t == 0:
+            deg[:] = 0
+            deg[7] = 300
+            deg[9] = fill - 300
+        parent[t, :fill] = rng.permutation(np.repeat(np.arange(tp), deg))
+        weight[t, :fill] = 1.0
+    return parent, code, weight
+
+
+def _blocked_oracle(parent, code, weight, ds):
+    tiles = parent.shape[0]
+    tp = segsum_kernel.TILE_PARENTS
+    ids = (np.arange(tiles)[:, None] * tp + parent) * ds + code
+    flat = jax.ops.segment_sum(jnp.asarray(weight.ravel()),
+                               jnp.asarray(ids.ravel()),
+                               num_segments=tiles * tp * ds)
+    return np.asarray(flat).reshape(tiles * tp, ds)
+
+
+@pytest.mark.parametrize("ds", [1, 9, 27])
+@pytest.mark.parametrize("tiles,slots", [(3, 512), (16, 640)])
+def test_blocked_kernel_equals_a_segment_sum(tiles, slots, ds):
+    """Exact: every count is an integer, and tile 0 holds a parent of
+    degree 300 (one pass of bfloat16 sums would round it).  Sixteen tiles
+    take two grid steps; three take one step of three rows."""
+    rng = np.random.default_rng(tiles * 100 + ds)
+    parent, code, weight = _blocked_layout(rng, tiles, slots, ds)
+    want = _blocked_oracle(parent, code, weight, ds)
+    assert want[7].sum() == 300
+    n_parent = tiles * segsum_kernel.TILE_PARENTS - 5
+    got = ops.blocked_ones_segment_sum(
+        jnp.asarray(parent), jnp.asarray(code), jnp.asarray(weight),
+        n_parent, ds, interpret=True)
+    assert got.shape == (n_parent, ds)
+    np.testing.assert_array_equal(np.asarray(got), want[:n_parent])
+    assert (np.asarray(got).sum(axis=1) == 0).any()      # empty parents
+
+
+def test_blocked_kernel_refuses_a_ragged_grid():
+    z = jnp.zeros((12, 128), jnp.int32)
+    with pytest.raises(ValueError, match="no multiple"):
+        segsum_kernel.blocked_segment_sum_pallas(
+            z, z, z.astype(jnp.float32), 3, interpret=True)
+
+
 # ------------------------------------------------- backend probe / routing ---
 def test_default_interpret_probe_and_env_override(monkeypatch):
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
@@ -93,6 +150,26 @@ def test_segsum_kernel_routing_predicate(monkeypatch):
         ops.SEGSUM_KERNEL_MAX_SEGMENTS + 1) is False
     monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "0")
     assert ops.segsum_kernel_enabled(256) is False
+
+
+def test_blocked_kernel_routing_predicate(monkeypatch):
+    monkeypatch.delenv("REPRO_SEGSUM_PALLAS", raising=False)
+    big = ops.BLOCKED_MIN_EDGES
+    # CPU default: XLA scatter
+    assert ops.blocked_segsum_enabled(big, 9) is False
+    monkeypatch.setattr(ops, "_on_accelerator", lambda: True)
+    assert ops.blocked_segsum_enabled(big, 9) is True
+    assert ops.blocked_segsum_enabled(big - 1, 9) is False   # delta views
+    assert ops.blocked_segsum_enabled(big, ops.BLOCKED_MAX_CODES) is True
+    assert ops.blocked_segsum_enabled(
+        big, ops.BLOCKED_MAX_CODES + 1) is False
+    monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "0")
+    assert ops.blocked_segsum_enabled(big, 9) is False
+    monkeypatch.setattr(ops, "_on_accelerator", lambda: False)
+    monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "1")
+    assert ops.blocked_segsum_enabled(10, 9) is True        # any edges
+    assert ops.blocked_segsum_enabled(
+        10, ops.BLOCKED_MAX_CODES + 1) is False
 
 
 def test_sparse_executor_kernel_route_parity(monkeypatch):

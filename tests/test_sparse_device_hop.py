@@ -9,10 +9,15 @@ kept there.
 * a delta view's device copies die with its arrays, and a store that
   shares an entity table reads the table's last write;
 * a second pre-count on a new store version with unchanged data compiles
-  nothing, stages no edge column and uploads no edge column.
+  nothing, stages no edge column and uploads no edge column;
+* leaf hops through the blocked kernel over parent-sorted layouts (forced
+  on by ``REPRO_SEGSUM_PALLAS=1``) equal the dense executor and the
+  oracle; a rewritten relationship rebuilds its layout, a hub parent
+  falls back to scatter, and the sharded executor keeps its flat path.
 """
 
 import gc
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +33,7 @@ from repro.core.executors import (_ROW_CHUNK, DenseExecutor, SparseExecutor,
 from repro.core.oracle import oracle_ct
 from repro.core.plan import compile_plan
 from repro.obs import Tracer
+from tests.test_distributed_counting import _run_subprocess
 from tests.test_mutations import fresh_pairs
 
 
@@ -255,3 +261,155 @@ def test_a_segment_space_past_int32_raises(stacked):
             ex.positive_batch(db, [plan, plan])
         else:
             ex.positive(db, plan)
+
+
+# ------------------------------------------------------- blocked leaf hops ---
+def blocked_db(seed=0):
+    """Relationships whose parent ends are small enough for a whole tile,
+    with more edges than two thirds of a tile's slots: every leaf hop of
+    a chain of up to two fits the blocked layout."""
+    att = lambda n, c=2: Attribute(n, c)
+    schema = Schema(
+        entities=(
+            EntityType("a", 8, (att("x", 3),)),
+            EntityType("b", 200, (att("z", 2),)),
+            EntityType("c", 8, (att("w", 2),)),
+            EntityType("d", 200, (att("v", 3),)),
+        ),
+        relationships=(
+            Relationship("R1", "a", "b", (att("e1", 2),)),
+            Relationship("R2", "b", "c", (att("e2", 3),)),
+            Relationship("R3", "c", "d", ()),
+        ),
+    )
+    return synth_db(schema, {"R1": 500, "R2": 500, "R3": 500}, seed=seed)
+
+
+def _traced_contract(engine, rels):
+    """One traced contraction: (table, its ``count.positive`` span)."""
+    tab = engine.contract(point_from_rels(engine.db.schema, rels))
+    span = [r for r in engine.executor.tracer.records()
+            if r.name == "count.positive"][-1]
+    return tab, span
+
+
+@pytest.mark.parametrize("rels", [["R1"], ["R2"], ["R3"], ["R1", "R2"],
+                                  ["R2", "R3"], ["R1", "R2", "R3"]],
+                         ids="-".join)
+def test_blocked_positives_equal_dense_and_oracle(rels, monkeypatch):
+    monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "1")
+    db = blocked_db()
+    ex = SparseExecutor()
+    ex.tracer = Tracer()
+    tab, span = _traced_contract(CountingEngine(db, ex), rels)
+    plan = compile_plan(db.schema, point_from_rels(db.schema, rels))
+    dense = DenseExecutor().positive(db, plan)
+    assert tab.vars == dense.vars
+    np.testing.assert_array_equal(np.asarray(tab.counts),
+                                  np.asarray(dense.counts))
+    if len(rels) < 3:           # the grounding oracle: 8 x 200 x 8 at most
+        want = oracle_ct(db, plan.point, tab.vars, require_positive=True)
+        np.testing.assert_array_equal(np.asarray(tab.counts), want)
+    # leaf hops run blocked; the path of three's dense-message hop scatters
+    dense_hops = _dense_message_hops(plan)
+    assert span.attrs["blocked_hops"] == plan.hops - dense_hops
+    assert span.attrs["scattered_hops"] == dense_hops
+    assert span.attrs["edges_uploaded"] == plan.hops
+
+
+def test_a_rewritten_relationship_rebuilds_its_layout(monkeypatch):
+    monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "1")
+    db = blocked_db(1)
+    ex = SparseExecutor()
+    ex.tracer = Tracer()
+    eng = CountingEngine(db, ex)
+    for _ in range(2):
+        _, span = _traced_contract(eng, ["R1"])
+    assert span.attrs["blocked_hops"] == span.attrs["edges_resident"] == 1
+    # the layout stands in for the edge columns: only entity columns
+    # have device copies
+    assert len(ex._layouts) == 1
+    assert set(ex._mirrors._live) == {(id(db.entities["a"].attrs["x"]),),
+                                      (id(db.entities["b"].attrs["z"]),)}
+    db.insert_facts("R1", *fresh_pairs(db, "R1", 4,
+                                       np.random.default_rng(7)),
+                    {"e1": [0, 1, 1, 0]})
+    gc.collect()
+    assert len(ex._layouts) == 0
+    tab, span = _traced_contract(eng, ["R1"])
+    assert span.attrs["blocked_hops"] == span.attrs["edges_uploaded"] == 1
+    assert len(ex._layouts) == 1
+    want = oracle_ct(db, point_from_rels(db.schema, ["R1"]), tab.vars,
+                     require_positive=True)
+    np.testing.assert_array_equal(np.asarray(tab.counts), want)
+
+
+def test_a_hub_parent_falls_back_to_scatter(monkeypatch):
+    """Two tiles of parents: 800 spread edges fit the layout; a parent
+    joined to all 1024 children makes its tile's slots, and so every
+    tile's, more than 1.5 times the edges."""
+    monkeypatch.setenv("REPRO_SEGSUM_PALLAS", "1")
+    schema = Schema(
+        entities=(EntityType("u", 512, (Attribute("x", 2),)),
+                  EntityType("v", 1024, (Attribute("y", 3),))),
+        relationships=(Relationship("H", "u", "v", (Attribute("e", 2),)),))
+    db = synth_db(schema, {"H": 800}, seed=3)
+    ex = SparseExecutor()
+    ex.tracer = Tracer()
+    eng = CountingEngine(db, ex)
+    plan = compile_plan(db.schema, point_from_rels(db.schema, ["H"]))
+    assert plan.root.var.etype == "u"
+    _, span = _traced_contract(eng, ["H"])
+    assert (span.attrs["blocked_hops"], span.attrs["scattered_hops"]) == (1, 0)
+
+    rt = db.relations["H"]
+    taken = set(rt.dst[rt.src == 0].tolist())
+    hub = np.array([j for j in range(1024) if j not in taken], np.int32)
+    db.insert_facts("H", np.zeros_like(hub), hub,
+                    {"e": (hub % 2).astype(np.int32)})
+    tab, span = _traced_contract(eng, ["H"])
+    assert (span.attrs["blocked_hops"], span.attrs["scattered_hops"]) == (0, 1)
+    np.testing.assert_array_equal(
+        np.asarray(tab.counts),
+        np.asarray(DenseExecutor().positive(db, plan).counts))
+
+
+SHARDED_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["REPRO_SEGSUM_PALLAS"] = "1"
+    import jax, numpy as np
+    from repro.core import CountingEngine, point_from_rels
+    from repro.core.distributed import ShardedSparseExecutor
+    from repro.core.executors import SparseExecutor
+    from repro.obs import Tracer
+    from tests.test_sparse_device_hop import blocked_db, _traced_contract
+
+    db = blocked_db()
+    mesh = jax.make_mesh((8,), ("data",))
+    for ex in (ShardedSparseExecutor(mesh=mesh, axis="data"),
+               SparseExecutor()):
+        ex.tracer = Tracer()
+        tab, span = _traced_contract(CountingEngine(db, ex), ["R1", "R2"])
+        counts = np.asarray(tab.counts)
+        if isinstance(ex, ShardedSparseExecutor):
+            assert ex.n_ranks == 8
+            assert (span.attrs["blocked_hops"],
+                    span.attrs["scattered_hops"]) == (0, 2), span.attrs
+            with ex.local_mode():          # one device: blocked again
+                local = ex.positive(db, CountingEngine(db, ex).plan(
+                    point_from_rels(db.schema, ["R1", "R2"])))
+            assert ex.blocked_hops == 2
+            np.testing.assert_array_equal(np.asarray(local.counts), counts)
+            sharded = counts
+        else:
+            assert span.attrs["blocked_hops"] == 2
+            np.testing.assert_array_equal(counts, sharded)
+    print("SHARDED-FLAT-OK")
+""")
+
+
+def test_the_sharded_executor_keeps_its_flat_path():
+    """On 8 virtual CPU devices a sharded hop scatters flat ids over the
+    mesh even where a single device would run the blocked kernel."""
+    assert "SHARDED-FLAT-OK" in _run_subprocess(SHARDED_SCRIPT)

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bdeu_kernel import bdeu_pallas
 from repro.kernels.hist_kernel import segment_hist_pallas
-from repro.kernels.segsum_kernel import (segment_sum_ones_pallas,
+from repro.kernels.segsum_kernel import (blocked_segment_sum_pallas,
+                                         segment_sum_ones_pallas,
                                          segment_sum_rows_pallas)
 
 I32, F32 = jnp.int32, jnp.float32
@@ -37,6 +38,12 @@ CASES = {
     "segsum_ones_1.9M_edges": (
         segment_sum_ones_pallas, dict(num_segments=32768),
         [((1_900_000,), I32), ((1_900_000,), F32)]),
+    # a leaf hop over one VisualGenome relationship in its blocked layout:
+    # 782 tiles of 256 parents (200 k), padded to 784 rows, of 2688 slots,
+    # into 3 x 3 codes
+    "blocked_segsum_784x2688_9codes": (
+        blocked_segment_sum_pallas, dict(num_codes=9),
+        [((784, 2688), I32), ((784, 2688), I32), ((784, 2688), F32)]),
     "segment_hist": (segment_hist_pallas, dict(num_segments=1000),
                      [((5000,), I32), ((5000, 48), F32)]),
     # Q > block_q: several Q-blocks, one partial tile each
@@ -93,5 +100,18 @@ def test_a_hop_gathers_codes_by_rows_in_bounded_steps(one_chip,
     compiled = _hop_segment_ids.lower(
         code, edges, edges, (edges,), ds=72, cards=(3,),
         by_rows=True).compile()
+    rows = _ROW_CHUNK * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * rows
+
+
+def test_a_blocked_hop_gathers_slot_codes_in_bounded_steps(
+        one_chip, no_persistent_cache):
+    """The same hop over its blocked layout: 784 x 3072 slots."""
+    from repro.core.executors import _ROW_CHUNK, _hop_slot_codes
+
+    slots = jax.ShapeDtypeStruct((784, 3072), I32, sharding=one_chip)
+    code = jax.ShapeDtypeStruct((200_000,), I32, sharding=one_chip)
+    compiled = _hop_slot_codes.lower(code, slots, (slots,), cards=(3,),
+                                     by_rows=True).compile()
     rows = _ROW_CHUNK * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * rows
